@@ -14,8 +14,7 @@ os.environ.setdefault("XLA_FLAGS", "--xla_force_host_platform_device_count=8")
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax import lax
-from repro.compat import shard_map
+from jax import lax, shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 from repro.core import collectives as mp

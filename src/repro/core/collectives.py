@@ -53,8 +53,8 @@ from typing import Dict, List, Mapping, Sequence, Tuple
 import jax
 import jax.numpy as jnp
 from jax import lax
+from jax.lax import axis_size
 
-from repro.compat import axis_size
 from repro.core.tuner import SHARE_GRID  # noqa: F401  (re-export for callers)
 from repro.kernels import ops as _kops
 

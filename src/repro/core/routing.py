@@ -29,8 +29,8 @@ from typing import Callable, Dict, Mapping, Optional, Sequence, Tuple
 import jax
 import jax.numpy as jnp
 from jax import lax
+from jax.lax import axis_size
 
-from repro.compat import axis_size
 from repro.core import collectives as cx
 from repro.core.collectives import (CHUNK_GRID, PATH_ORDER, PATH_ORTHO,
                                     PATH_PRIMARY, PATH_STAGED)

@@ -1,9 +1,9 @@
 """Jitted public wrappers around the Pallas kernels.
 
 These handle the padding/alignment contracts (arbitrary shapes -> lane- and
-block-aligned payloads) and pick interpret mode automatically: compiled on
-TPU, interpret=True everywhere else so CPU tests execute the same kernel
-body.
+block-aligned payloads) and pick the kernel mode from the default backend:
+compiled on TPU, interpret=True on CPU so CPU tests execute the same kernel
+body.  Any other backend is an error, never a quiet interpreted run.
 """
 
 from __future__ import annotations
@@ -21,7 +21,12 @@ from repro.kernels import payload_partition as _pp
 
 
 def _interpret() -> bool:
-    return jax.default_backend() != "tpu"
+    backend = jax.default_backend()
+    if backend not in ("tpu", "cpu"):
+        raise RuntimeError(
+            f"Pallas kernels compile for TPU and interpret on CPU; the "
+            f"default backend is {backend!r}")
+    return backend == "cpu"
 
 
 def _pad_2d(x: jax.Array) -> jax.Array:
@@ -153,7 +158,7 @@ def paged_flash_decode(q: jax.Array, k_pool: jax.Array, v_pool: jax.Array,
                        window=None) -> jax.Array:
     """Flash-decoding over a paged KV pool (one layer): q [T, Hq, hd],
     pools [n_blocks, block_size, Hkv, hd], block_tables [T, maxb],
-    kv_valid [T] -> [T, Hq, hd].  Compiled on TPU, interpret elsewhere."""
+    kv_valid [T] -> [T, Hq, hd].  Compiled on TPU, interpreted on CPU."""
     return _fd.paged_flash_decode_pool(q, k_pool, v_pool, block_tables,
                                        kv_valid, window=window,
                                        interpret=_interpret())

@@ -243,9 +243,6 @@ def run_one(arch: str, shape_name: str, multi_pod: bool,
               flush=True)
 
     cost = compiled.cost_analysis() or {}
-    # older JAX returns a one-element list of dicts (one per computation)
-    if isinstance(cost, (list, tuple)):
-        cost = cost[0] if cost else {}
     mem = None
     mem_report = {}
     try:

@@ -9,6 +9,7 @@ continuous-batching paged engine (DESIGN.md §13).
 from __future__ import annotations
 
 import argparse
+import dataclasses
 import json
 import os
 import sys
@@ -19,6 +20,7 @@ import numpy as np
 
 from repro.configs import ALIASES, get_config
 from repro.core.communicator import CommConfig
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models.tp import ParallelCtx
 from repro.models.transformer import init_params
 from repro.serving.engine import (PagedServeConfig, PagedServeEngine,
@@ -46,6 +48,9 @@ def main(argv=None) -> int:
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="glm4-9b", choices=sorted(ALIASES))
     ap.add_argument("--smoke", action="store_true")
+    ap.add_argument("--layers", type=int, default=0,
+                    help="cut the depth to N layers at the config's own "
+                         "widths (0 = the published depth)")
     ap.add_argument("--requests", type=int, default=8)
     ap.add_argument("--max-new", type=int, default=12)
     ap.add_argument("--slots", type=int, default=4)
@@ -113,6 +118,8 @@ def main(argv=None) -> int:
     cfg = get_config(args.arch)
     if args.smoke:
         cfg = cfg.reduced()
+    if args.layers:
+        cfg = dataclasses.replace(cfg, n_layers=args.layers)
 
     # single-device ctx, but with the comm config plumbed so a multi-axis
     # deployment of this launcher inherits the control-plane flags
@@ -246,4 +253,5 @@ def main(argv=None) -> int:
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     sys.exit(main())

@@ -26,20 +26,19 @@ from typing import Any, Dict, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+from jax import shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
-
-from repro.compat import shard_map
 
 from repro.core.communicator import CommConfig
 from repro.launch.mesh import mesh_dims, mesh_nodes
 from repro.launch import shapes as SH
 from repro.models.config import ArchConfig
 from repro.models.tp import ParallelCtx
-from repro.models.transformer import (decode_step, forward, lm_logits_local,
-                                      lm_loss, param_specs)
-from repro.optim.adamw import AdamWConfig, AdamWState
+from repro.models.transformer import (decode_step, forward, init_params,
+                                      lm_logits_local, lm_loss, param_specs)
+from repro.optim.adamw import AdamWConfig, AdamWState, init_state
 from repro.runtime.program import StepProgram
-from repro.train.train_step import make_train_step
+from repro.train.train_step import ef_init_residuals, make_train_step
 
 
 def make_ctx(mesh: Mesh, comm: Optional[CommConfig] = None,
@@ -69,14 +68,13 @@ def _batch_specs(cfg: ArchConfig, shape: SH.InputShape, mesh) -> Dict:
                                     nodes=mesh_nodes(mesh))
 
 
-def _train_builder(cfg: ArchConfig, mesh: Mesh, *,
-                   comm: Optional[CommConfig],
-                   opt: Optional[AdamWConfig],
-                   shape: Optional[SH.InputShape],
-                   remat: bool, cluster=None, bucket_mb: float = 0.0):
-    ctx = make_ctx(mesh, comm, cluster=cluster)
-    opt = opt or AdamWConfig()
-    shape = shape or SH.SHAPES["train_4k"]
+def _named(mesh: Mesh, specs):
+    return jax.tree.map(lambda s: NamedSharding(mesh, s), specs,
+                        is_leaf=lambda x: isinstance(x, P))
+
+
+def _train_state_specs(cfg: ArchConfig, ctx: ParallelCtx,
+                       bucket_mb: float) -> Tuple[Any, Any]:
     # the expert dim shards over the ctx's ep span (data, plus node/pod
     # on a cluster mesh — DESIGN.md §15); ctx and specs must agree on
     # the combined rank order, so the ctx is the single authority
@@ -87,7 +85,38 @@ def _train_builder(cfg: ArchConfig, mesh: Mesh, *,
         # (AdamWState, residuals) — the error-feedback residual tree is
         # param-shaped, so it shards exactly like the params
         osp = (osp, psp)
+    return psp, osp
+
+
+def init_train_state(cfg: ArchConfig, mesh: Mesh, ctx: ParallelCtx, key, *,
+                     bucket_mb: float = 0.0):
+    """(params, opt_state) built directly in the train step's layout.
+    Each device materializes only its own shards, so a model whose
+    unsharded params + AdamW moments exceed one device still starts."""
+    psp, osp = _train_state_specs(cfg, ctx, bucket_mb)
+
+    def init(key):
+        params = init_params(key, cfg)
+        opt_state = init_state(params)
+        if not isinstance(osp, AdamWState):     # (AdamWState, residuals)
+            opt_state = (opt_state, ef_init_residuals(params))
+        return params, opt_state
+
+    return jax.jit(init, out_shardings=(_named(mesh, psp),
+                                        _named(mesh, osp)))(key)
+
+
+def _train_builder(cfg: ArchConfig, mesh: Mesh, *,
+                   comm: Optional[CommConfig],
+                   opt: Optional[AdamWConfig],
+                   shape: Optional[SH.InputShape],
+                   remat: bool, cluster=None, bucket_mb: float = 0.0):
+    ctx = make_ctx(mesh, comm, cluster=cluster)
+    opt = opt or AdamWConfig()
+    shape = shape or SH.SHAPES["train_4k"]
+    psp, osp = _train_state_specs(cfg, ctx, bucket_mb)
     bsp = _batch_specs(cfg, shape, mesh)
+    state_sh = (_named(mesh, psp), _named(mesh, osp))
 
     def builder():
         # a FRESH closure + jit per build: jax.jit memoizes per function
@@ -100,8 +129,14 @@ def _train_builder(cfg: ArchConfig, mesh: Mesh, *,
                             out_specs=(psp, osp, P()),
                             check_vma=False)
         # donate params + optimizer state: they are consumed and re-emitted
-        # every step — aliasing halves the peak parameter memory.
-        return jax.jit(sharded, donate_argnums=(0, 1))
+        # every step — aliasing halves the peak parameter memory.  The
+        # explicit shardings let jit pair each donated buffer with the
+        # output of the same layout: left to XLA, a buffer could alias an
+        # output of equal global shape but another per-device shape.
+        return jax.jit(sharded, donate_argnums=(0, 1),
+                       in_shardings=(*state_sh, _named(mesh, bsp)),
+                       out_shardings=(*state_sh,
+                                      NamedSharding(mesh, P())))
 
     return builder, ctx
 
@@ -225,7 +260,6 @@ def build_serve_program(cfg: ArchConfig, mesh: Mesh, shape: SH.InputShape, *,
 
 def eval_shape_params(cfg: ArchConfig):
     """ShapeDtypeStruct param tree — NO allocation (dry-run pattern)."""
-    from repro.models.transformer import init_params
     return jax.eval_shape(
         lambda key: init_params(key, cfg), jax.random.PRNGKey(0))
 

@@ -4,13 +4,15 @@
       --steps 50 --mesh-shape 2,4
 
 ``--smoke`` swaps in the reduced config (2 layers, d_model<=512) so the
-driver runs on CPU; the FULL configs are exercised by the dry-run only.
-The mesh shape is (data, model) — on real hardware use (16,16) per pod.
+launcher runs on CPU; ``--layers N`` keeps the published widths and cuts
+only the depth, the size a chip run uses.  The mesh shape is
+(data, model) — on real hardware use (16,16) per pod.
 """
 
 from __future__ import annotations
 
 import argparse
+import dataclasses
 import sys
 
 import jax
@@ -21,11 +23,11 @@ from repro.configs import ALIASES, get_config
 from repro.core.communicator import CommConfig
 from repro.data.pipeline import make_batches
 from repro.launch import shapes as SH
+from repro.launch.compile_cache import enable_compile_cache
 from repro.launch.mesh import (make_cluster_mesh, make_mesh,
                                make_production_mesh, mesh_dims, mesh_nodes)
-from repro.launch.steps import build_train_program
-from repro.models.transformer import init_params
-from repro.optim.adamw import AdamWConfig, init_state
+from repro.launch.steps import build_train_program, init_train_state
+from repro.optim.adamw import AdamWConfig
 from repro.train.loop import LoopConfig, run_loop
 
 
@@ -34,6 +36,9 @@ def main(argv=None) -> int:
     ap.add_argument("--arch", default="glm4-9b", choices=sorted(ALIASES))
     ap.add_argument("--smoke", action="store_true",
                     help="reduced config (CPU-runnable)")
+    ap.add_argument("--layers", type=int, default=0,
+                    help="cut the depth to N layers at the config's own "
+                         "widths (0 = the published depth)")
     ap.add_argument("--steps", type=int, default=50)
     ap.add_argument("--seq-len", type=int, default=128)
     ap.add_argument("--batch", type=int, default=8, help="global batch")
@@ -107,6 +112,8 @@ def main(argv=None) -> int:
     cfg = get_config(args.arch)
     if args.smoke:
         cfg = cfg.reduced()
+    if args.layers:
+        cfg = dataclasses.replace(cfg, n_layers=args.layers)
     shape = SH.InputShape("cli", "train", args.seq_len, args.batch)
 
     from repro.configs.clusters import resolve_cluster, resolve_faults
@@ -151,20 +158,16 @@ def main(argv=None) -> int:
                       total_steps=args.steps)
 
     with mesh:
-        params = init_params(jax.random.PRNGKey(0), cfg)
-        opt_state = init_state(params)
-
         # StepProgram: plan-keyed executable cache + per-program Stage-2
         # replay recorder — the loop never re-jits a plan it already
         # compiled (DESIGN.md §7).
         program, ctx = build_train_program(cfg, mesh, comm=comm, opt=opt,
                                            shape=shape, cluster=cluster,
                                            bucket_mb=args.bucket_mb)
-        if args.bucket_mb > 0 and ctx.ef_codec_name():
-            # lossy wire codec: the error-feedback residuals ride the
-            # optimizer state (train_step.py docstring)
-            from repro.train.train_step import ef_init_residuals
-            opt_state = (opt_state, ef_init_residuals(params))
+        # a lossy wire codec adds the error-feedback residuals to the
+        # optimizer state (train_step.py docstring)
+        params, opt_state = init_train_state(
+            cfg, mesh, ctx, jax.random.PRNGKey(0), bucket_mb=args.bucket_mb)
         batches_fn = lambda: make_batches(  # noqa: E731
             cfg, seq_len=args.seq_len, batch_per_shard=args.batch)
         clock = handler = None
@@ -191,7 +194,7 @@ def main(argv=None) -> int:
     if args.out:
         import json
         import os
-        rep = {"final_loss": hist[-1], "steps": args.steps,
+        rep = {"final_loss": hist[-1], "losses": hist, "steps": args.steps,
                **(loop.report or {})}
         if clock is not None:
             rep["faults"] = clock.report()
@@ -204,4 +207,5 @@ def main(argv=None) -> int:
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     sys.exit(main())

@@ -23,8 +23,8 @@ from typing import Dict, Optional, Tuple
 import jax
 import jax.numpy as jnp
 from jax import lax
+from jax.lax import axis_size
 
-from repro.compat import axis_size
 from repro.core.communicator import (CommConfig, FlexCommunicator,
                                      comm_init_rank)
 

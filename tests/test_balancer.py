@@ -1,7 +1,7 @@
 """Stage-2 runtime balancer (Evaluator + LoadBalancer) tests."""
 
 import pytest
-from _hyp import given, settings, st
+from hypothesis import given, settings, strategies as st
 
 from repro.core.balancer import Evaluator, LoadBalancer
 from repro.core.simulator import MiB, PathTimingModel

@@ -194,7 +194,7 @@ def _grad_of(collective, codec, x):
     """
     from jax import lax
     from jax.sharding import PartitionSpec as P
-    from repro.compat import shard_map
+    from jax import shard_map
     from repro.core import collectives as mp
 
     def shard(xs):

@@ -12,7 +12,7 @@ import numpy as np
 import pytest
 from jax.sharding import Mesh, PartitionSpec as P
 
-from repro.compat import shard_map
+from jax import shard_map
 from repro.control import (MeasuredTimingSource, SimTimingSource,
                            SlotController, TuningProfile)
 from repro.core.balancer import LoadBalancer

@@ -4,7 +4,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
-from _hyp import given, settings, st
+from hypothesis import given, settings, strategies as st
 
 from repro.kernels import ops, ref
 from repro.kernels.chunk_accumulate import LANE, SUBLANE, chunk_accumulate_2d
@@ -65,7 +65,7 @@ def test_accumulate_is_ring_pluggable():
     import jax
     from jax import lax
     from jax.sharding import Mesh, PartitionSpec as P
-    from repro.compat import shard_map
+    from jax import shard_map
     from repro.core.collectives import ring_all_reduce
     if len(jax.devices()) < 8:
         pytest.skip("needs 8 devices")

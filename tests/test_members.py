@@ -16,7 +16,7 @@ import json
 
 import pytest
 
-from _hyp import given, settings, st
+from hypothesis import given, settings, strategies as st
 
 from repro.cluster.topology import (degrade_cluster, make_cluster,
                                     make_nic_tier)

@@ -5,7 +5,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
-from _hyp import given, settings, st
+from hypothesis import given, settings, strategies as st
 
 from repro.models import (ArchConfig, DecodeConfig, MoEConfig, SSMConfig,
                           HybridConfig, EncDecConfig, VLMConfig,

@@ -15,11 +15,11 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 import pytest
-from _hyp import given, settings, st
+from hypothesis import given, settings, strategies as st
 from jax import lax
 from jax.sharding import Mesh, PartitionSpec as P
 
-from repro.compat import shard_map
+from jax import shard_map
 from repro.core.communicator import (CommConfig, FlexCommunicator,
                                      comm_destroy_all, comm_init_rank)
 from repro.core.links import PROFILES

@@ -2,7 +2,7 @@
 
 import numpy as np
 import pytest
-from _hyp import given, settings, st
+from hypothesis import given, settings, strategies as st
 
 from repro.core.pipeline import (MonotonicPipe, StageTimes, N_BUFFERS,
                                  optimal_chunk_bytes, pipeline_time_s)

@@ -13,7 +13,7 @@ import numpy as np
 import pytest
 from jax.sharding import Mesh, PartitionSpec as P
 
-from repro.compat import shard_map
+from jax import shard_map
 from repro.core.communicator import (CommConfig, FlexCommunicator,
                                      bucket_for, comm_destroy_all,
                                      comm_init_rank)

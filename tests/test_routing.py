@@ -8,7 +8,7 @@ import pytest
 from jax import lax
 from jax.sharding import Mesh, PartitionSpec as P
 
-from repro.compat import shard_map
+from jax import shard_map
 from repro.core import routing as rt
 from repro.core.collectives import (CHUNK_GRID, PATH_ORDER, PATH_ORTHO,
                                     PATH_PRIMARY, PATH_STAGED)

@@ -3,7 +3,7 @@
 import math
 
 import pytest
-from _hyp import given, settings, st
+from hypothesis import given, settings, strategies as st
 
 from repro.core.simulator import MiB, PathTimingModel
 from repro.core.topology import Collective
