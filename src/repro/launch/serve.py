@@ -200,6 +200,11 @@ def main(argv=None) -> int:
               f"({bc['hits']} hits / {bc['rebuilds']} rebuilds)")
         print(f"serving: {srv['scheduler']['preemptions']} preemptions, "
               f"kv blocks peak {kv['peak_in_use']}/{kv['total']}")
+        pf = srv["scheduler"]["prefill"]
+        if pf["requests"]:
+            print(f"serving: admission to first token p80 "
+                  f"{pf['p80_ms']:.1f} ms over {pf['requests']} requests, "
+                  f"no row in {100 * pf['stall_share']:.1f}% of those ticks")
     if clock is not None:
         fr = clock.report()
         print(f"faults: {len(fr['transitions'])} transition(s), "

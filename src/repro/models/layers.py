@@ -465,6 +465,10 @@ def paged_attention_block(p, x: jax.Array, cfg: ArchConfig,
     scatter to a dropped out-of-bounds index (zero pool writes) and read
     an all-masked accumulator (exact-zero output).
 
+    The ops carry named scopes for the device trace (metadata only):
+    ``qkv_proj``; ``attn`` with ``kv_write``, ``kv_gather`` and
+    ``attend``; ``o_proj``.
+
     Returns (out [T, 1, D], (new_k_pool, new_v_pool)).
     """
     b, s, d = x.shape
@@ -474,57 +478,69 @@ def paged_attention_block(p, x: jax.Array, cfg: ArchConfig,
     window = cfg.sliding_window if window_override == "cfg" \
         else window_override
 
-    q = jnp.einsum("bsd,df->bsf", x, p["wq"])
-    if "bq" in p:
-        q = q + p["bq"]
-    q = q.reshape(b, s, hq_l, hd)
-    wk, bk = _kv_slice(p, cfg, ctx, "k")
-    wv, bv = _kv_slice(p, cfg, ctx, "v")
-    k = jnp.einsum("bsd,df->bsf", x, wk)
-    v = jnp.einsum("bsd,df->bsf", x, wv)
-    if bk is not None:
-        k, v = k + bk, v + bv
-    k = k.reshape(b, s, kv_w, hd)
-    v = v.reshape(b, s, kv_w, hd)
-    if cfg.rope_theta:
-        pos2 = positions[:, None]                 # [T, 1] per-row
-        q = apply_rope(q, pos2, cfg.rope_theta)
-        k = apply_rope(k, pos2, cfg.rope_theta)
+    with jax.named_scope("qkv_proj"):
+        q = jnp.einsum("bsd,df->bsf", x, p["wq"])
+        if "bq" in p:
+            q = q + p["bq"]
+        q = q.reshape(b, s, hq_l, hd)
+        wk, bk = _kv_slice(p, cfg, ctx, "k")
+        wv, bv = _kv_slice(p, cfg, ctx, "v")
+        k = jnp.einsum("bsd,df->bsf", x, wk)
+        v = jnp.einsum("bsd,df->bsf", x, wv)
+        if bk is not None:
+            k, v = k + bk, v + bv
+        k = k.reshape(b, s, kv_w, hd)
+        v = v.reshape(b, s, kv_w, hd)
+        if cfg.rope_theta:
+            pos2 = positions[:, None]             # [T, 1] per-row
+            q = apply_rope(q, pos2, cfg.rope_theta)
+            k = apply_rope(k, pos2, cfg.rope_theta)
 
     kp, vp = pools
     nb, bs_blk = kp.shape[0], kp.shape[1]
-    blk = positions // bs_blk
-    off = positions % bs_blk
-    phys = jnp.take_along_axis(block_tables, blk[:, None], axis=1)[:, 0]
-    # padding rows write nowhere: OOB destination + mode="drop"
-    dest = jnp.where(kv_valid > 0, phys * bs_blk + off, nb * bs_blk)
     kp_flat = kp.reshape(nb * bs_blk, kv_w, hd)
     vp_flat = vp.reshape(nb * bs_blk, kv_w, hd)
-    kp_flat = kp_flat.at[dest].set(k[:, 0].astype(kp.dtype), mode="drop")
-    vp_flat = vp_flat.at[dest].set(v[:, 0].astype(vp.dtype), mode="drop")
-    new_pools = (kp_flat.reshape(kp.shape), vp_flat.reshape(vp.shape))
+    with jax.named_scope("attn"):
+        with jax.named_scope("kv_write"):
+            blk = positions // bs_blk
+            off = positions % bs_blk
+            phys = jnp.take_along_axis(block_tables, blk[:, None],
+                                       axis=1)[:, 0]
+            # padding rows write nowhere: OOB destination + mode="drop"
+            dest = jnp.where(kv_valid > 0, phys * bs_blk + off, nb * bs_blk)
+            kp_flat = kp_flat.at[dest].set(k[:, 0].astype(kp.dtype),
+                                           mode="drop")
+            vp_flat = vp_flat.at[dest].set(v[:, 0].astype(vp.dtype),
+                                           mode="drop")
+            new_pools = (kp_flat.reshape(kp.shape), vp_flat.reshape(vp.shape))
 
-    if impl == "kernel":
-        from repro.kernels import ops as K
-        out = K.paged_flash_decode(q[:, 0], new_pools[0], new_pools[1],
-                                   block_tables, kv_valid,
-                                   window=window)[:, None]
-    else:
-        # dense block-gather reference: index i of the gathered view IS
-        # position i, so this call matches the wave engine's dense-cache
-        # chunked_attention bit for bit (same chunking, same masks; stale
-        # lanes beyond kv_valid contribute exact zeros either way).
-        maxb = block_tables.shape[1]
-        s_len = maxb * bs_blk
-        src = (block_tables[:, :, None] * bs_blk +
-               jnp.arange(bs_blk)[None, None, :]).reshape(b, s_len)
-        kg = kp_flat[src]                         # [T, S, kv_w, hd]
-        vg = vp_flat[src]
-        out = chunked_attention(q, kg, vg, causal=True, window=window,
-                                q_offset=positions, kv_valid=kv_valid)
+        if impl == "kernel":
+            from repro.kernels import ops as K
+            with jax.named_scope("attend"):
+                out = K.paged_flash_decode(q[:, 0], new_pools[0],
+                                           new_pools[1], block_tables,
+                                           kv_valid, window=window)[:, None]
+        else:
+            # dense block-gather reference: index i of the gathered view
+            # IS position i, so this call matches the wave engine's
+            # dense-cache chunked_attention bit for bit (same chunking,
+            # same masks; stale lanes beyond kv_valid contribute exact
+            # zeros either way).
+            with jax.named_scope("kv_gather"):
+                maxb = block_tables.shape[1]
+                s_len = maxb * bs_blk
+                src = (block_tables[:, :, None] * bs_blk +
+                       jnp.arange(bs_blk)[None, None, :]).reshape(b, s_len)
+                kg = kp_flat[src]                 # [T, S, kv_w, hd]
+                vg = vp_flat[src]
+            with jax.named_scope("attend"):
+                out = chunked_attention(q, kg, vg, causal=True,
+                                        window=window, q_offset=positions,
+                                        kv_valid=kv_valid)
 
-    o = jnp.einsum("bsf,fd->bsd", out.reshape(b, s, hq_l * hd), p["wo"])
-    o = ctx.tp_all_reduce(o)       # row-parallel combine — FlexLink path
+    with jax.named_scope("o_proj"):
+        o = jnp.einsum("bsf,fd->bsd", out.reshape(b, s, hq_l * hd), p["wo"])
+        o = ctx.tp_all_reduce(o)   # row-parallel combine — FlexLink path
     return o, new_pools
 
 

@@ -545,58 +545,74 @@ def paged_decode_step(p, pool, tokens: jax.Array, positions: jax.Array,
 
     Returns (logits [R, V_local], new pool).  Padding rows cost zero
     attention mass and zero pool writes (layers.paged_attention_block).
+    Named scopes tag the compiled ops for the device trace: ``embed``;
+    ``layers`` around the layer loop, inside it the attention sublayer's
+    own (``qkv_proj``, ``attn/...``, ``o_proj``) and ``mlp`` (or
+    ``moe``); ``head``.
     """
     fam = cfg.family
     if fam not in PAGED_FAMILIES:
         raise ValueError(fam)
     valid = row_req >= 0
     n_req = block_tables.shape[0]
-    btab = block_tables[jnp.clip(row_req, 0, n_req - 1)]     # [T, maxb]
-    kv_valid = jnp.where(valid, positions + 1, 0)
-    x = embed_tokens(p, tokens[:, None], cfg, ctx)           # [T, 1, D]
+    with jax.named_scope("attn"):
+        btab = block_tables[jnp.clip(row_req, 0, n_req - 1)]  # [T, maxb]
+        kv_valid = jnp.where(valid, positions + 1, 0)
+    with jax.named_scope("embed"):
+        x = embed_tokens(p, tokens[:, None], cfg, ctx)       # [T, 1, D]
 
     def attn(lp, x, kp, vp):
+        with jax.named_scope("qkv_proj"):
+            h = L.rms_norm(x, lp["ln1"], cfg.norm_eps)
         h, new_pools = L.paged_attention_block(
-            lp["attn"], L.rms_norm(x, lp["ln1"], cfg.norm_eps), cfg, ctx,
+            lp["attn"], h, cfg, ctx,
             positions=positions, kv_valid=kv_valid, pools=(kp, vp),
             block_tables=btab, window_override=pcfg.window_override,
             impl=pcfg.attn_impl)
         return x + h, new_pools
 
+    def mlp(lp, x):
+        with jax.named_scope("mlp"):
+            return x + L.mlp_block(
+                lp["mlp"], L.rms_norm(x, lp["ln2"], cfg.norm_eps), ctx)
+
     def step(x, inp):
         lp, kp, vp = inp
         x, (nkp, nvp) = attn(lp, x, kp, vp)
         if "mlp" in lp:
-            x = x + L.mlp_block(
-                lp["mlp"], L.rms_norm(x, lp["ln2"], cfg.norm_eps), ctx)
+            x = mlp(lp, x)
         else:
-            y, _ = M.moe_block(
-                lp["moe"], L.rms_norm(x, lp["ln2"], cfg.norm_eps),
-                cfg, ctx)
+            with jax.named_scope("moe"):
+                y, _ = M.moe_block(
+                    lp["moe"], L.rms_norm(x, lp["ln2"], cfg.norm_eps),
+                    cfg, ctx)
             x = x + y
         return x, (nkp, nvp)
 
     pool_k, pool_v = pool["k"], pool["v"]
-    if fam == "moe" and "prefix" in p:
-        npre = cfg.moe.n_dense_prefix
-        for i in range(npre):
-            lp = jax.tree.map(lambda a: a[i], p["prefix"])
-            x, (nkp, nvp) = attn(lp, x, pool_k[i], pool_v[i])
-            pool_k = pool_k.at[i].set(nkp)
-            pool_v = pool_v.at[i].set(nvp)
-            x = x + L.mlp_block(
-                lp["mlp"], L.rms_norm(x, lp["ln2"], cfg.norm_eps), ctx)
-        x, (nkp, nvp) = lax.scan(step, x, (p["layers"], pool_k[npre:],
-                                           pool_v[npre:]))
-        pool_k = pool_k.at[npre:].set(nkp)
-        pool_v = pool_v.at[npre:].set(nvp)
-    else:
-        x, (pool_k, pool_v) = lax.scan(step, x, (p["layers"], pool_k,
-                                                 pool_v))
+    # "layers": the layer loop's own ops (each layer's weights and pool
+    # sliced from the stacks, the updated pool written back)
+    with jax.named_scope("layers"):
+        if fam == "moe" and "prefix" in p:
+            npre = cfg.moe.n_dense_prefix
+            for i in range(npre):
+                lp = jax.tree.map(lambda a: a[i], p["prefix"])
+                x, (nkp, nvp) = attn(lp, x, pool_k[i], pool_v[i])
+                pool_k = pool_k.at[i].set(nkp)
+                pool_v = pool_v.at[i].set(nvp)
+                x = mlp(lp, x)
+            x, (nkp, nvp) = lax.scan(step, x, (p["layers"], pool_k[npre:],
+                                               pool_v[npre:]))
+            pool_k = pool_k.at[npre:].set(nkp)
+            pool_v = pool_v.at[npre:].set(nvp)
+        else:
+            x, (pool_k, pool_v) = lax.scan(step, x, (p["layers"], pool_k,
+                                                     pool_v))
 
-    x = L.rms_norm(x, p["final_norm"], cfg.norm_eps)
-    xs = x[jnp.clip(sample_rows, 0, x.shape[0] - 1)]         # [R, 1, D]
-    logits_l = lm_logits_local(p, xs, cfg, ctx)[:, 0]        # [R, V_l]
+    with jax.named_scope("head"):
+        x = L.rms_norm(x, p["final_norm"], cfg.norm_eps)
+        xs = x[jnp.clip(sample_rows, 0, x.shape[0] - 1)]     # [R, 1, D]
+        logits_l = lm_logits_local(p, xs, cfg, ctx)[:, 0]    # [R, V_l]
     return logits_l, {"k": pool_k, "v": pool_v}
 
 

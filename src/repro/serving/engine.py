@@ -28,11 +28,13 @@ loop, another engine) sharing the same memoized communicators.
 from __future__ import annotations
 
 import dataclasses
+import functools
 from typing import Dict, List, Optional
 
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from repro.models.config import ArchConfig
 from repro.models.tp import ParallelCtx
@@ -258,6 +260,51 @@ class PagedServeConfig:
     eos_id: int = -1
     attn_impl: str = "reference"
 
+    def paged(self) -> PagedConfig:
+        """The pool and step shape this configuration serves with."""
+        maxb = -(-self.cache_len // self.kv_block)
+        return PagedConfig(block_size=self.kv_block,
+                           n_blocks=self.n_blocks or maxb * self.max_requests,
+                           max_blocks_per_req=maxb,
+                           attn_impl=self.attn_impl)
+
+    def buckets(self) -> List[int]:
+        """The power-of-two batch-shape ladder, topped by the exact
+        budget."""
+        out: List[int] = []
+        b = max(1, self.min_bucket)
+        while b < self.max_tokens_in_flight:
+            out.append(b)
+            b *= 2
+        return out + [self.max_tokens_in_flight]
+
+
+def paged_step_builder(cfg: ArchConfig, ctx: ParallelCtx,
+                       pcfg: PagedConfig):
+    """A FRESH jit wrapper of the packed step (jax.jit memoizes per
+    function identity).  The named function gives the compiled module a
+    stable name (``jit_paged_step``) in the device trace."""
+    def paged_step(p, pool, toks, pos, rows, tables, sample):
+        return paged_decode_step(p, pool, toks, pos, rows, tables, sample,
+                                 cfg, ctx, pcfg)
+    return jax.jit(paged_step)
+
+
+def paged_step_texts(cfg: ArchConfig, ctx: ParallelCtx,
+                     scfg: PagedServeConfig, params) -> List[str]:
+    """The compiled HLO text of the packed step that an engine of
+    ``scfg`` runs, at each bucket of its ladder.  ``params`` may be
+    arrays or ``jax.ShapeDtypeStruct``s; the step's other arguments are
+    built here, as ``tick`` packs them."""
+    pcfg = scfg.paged()
+    pool = jax.eval_shape(functools.partial(init_paged_pool, cfg, ctx, pcfg))
+    step = paged_step_builder(cfg, ctx, pcfg)
+    i32 = lambda *s: jax.ShapeDtypeStruct(s, jnp.int32)  # noqa: E731
+    reqs = scfg.max_requests
+    return [step.lower(params, pool, i32(b), i32(b), i32(b),
+                       i32(reqs, pcfg.max_blocks_per_req), i32(reqs))
+            .compile().as_text() for b in scfg.buckets()]
+
 
 class PagedServeEngine:
     """In-flight (continuous) batching: requests are admitted into free
@@ -290,26 +337,16 @@ class PagedServeEngine:
         self.cfg = cfg
         self.ctx = ctx
         self.scfg = scfg
-        maxb = -(-scfg.cache_len // scfg.kv_block)
-        n_blocks = scfg.n_blocks or maxb * scfg.max_requests
-        self.pcfg = PagedConfig(block_size=scfg.kv_block,
-                                n_blocks=n_blocks,
-                                max_blocks_per_req=maxb,
-                                attn_impl=scfg.attn_impl)
+        self.pcfg = scfg.paged()
         self.pool = init_paged_pool(cfg, ctx, self.pcfg)
-        self.kv = PagedKVCache(n_blocks, scfg.kv_block, maxb,
+        self.kv = PagedKVCache(self.pcfg.n_blocks, scfg.kv_block,
+                               self.pcfg.max_blocks_per_req,
                                scfg.max_requests)
         self.sched = ContinuousScheduler(
             self.kv, max_requests=scfg.max_requests,
             max_tokens_in_flight=scfg.max_tokens_in_flight,
             eos_id=scfg.eos_id)
-        # power-of-two bucket ladder, topped by the exact budget
-        self.buckets: List[int] = []
-        b = max(1, scfg.min_bucket)
-        while b < scfg.max_tokens_in_flight:
-            self.buckets.append(b)
-            b *= 2
-        self.buckets.append(scfg.max_tokens_in_flight)
+        self.buckets = scfg.buckets()
         self.rng = np.random.default_rng(seed)
         self._next_rid = 0
         self._finished: Dict[int, List[int]] = {}
@@ -321,17 +358,13 @@ class PagedServeEngine:
         self._real_rows = 0
         self._padded_rows = 0
         self._peak_rows = 0
-        self._last_rows = 0
         self._bucket_steps: Dict[int, int] = {}
 
     def _step_builder(self):
-        """A FRESH jit wrapper per build (jax.jit memoizes per function
-        identity); the shape_key bucket keeps each padded-shape variant on
-        its own cache entry, so one wrapper never retraces silently."""
-        return jax.jit(
-            lambda p, pool, toks, pos, rows, tables, sample:
-            paged_decode_step(p, pool, toks, pos, rows, tables, sample,
-                              self.cfg, self.ctx, self.pcfg))
+        """A fresh jit wrapper per build; the shape_key bucket keeps each
+        padded-shape variant on its own cache entry, so one wrapper never
+        retraces silently."""
+        return paged_step_builder(self.cfg, self.ctx, self.pcfg)
 
     def _bucket(self, n_rows: int) -> int:
         for b in self.buckets:
@@ -369,44 +402,56 @@ class PagedServeEngine:
     def tick(self) -> int:
         """Plan (admit / pack / maybe preempt), run ONE fused packed step,
         sample sequence-frontier rows, retire finished requests.  Returns
-        the number of real (non-padding) rows processed."""
-        if self.ctx.fault_clock is not None:
-            self.ctx.fault_clock.advance(self._ticks)
-        self._ticks += 1
-        plan = self.sched.plan_tick()
+        the number of real (non-padding) rows processed.
+
+        Each phase is a profiler span, back to back over the whole body:
+        ``serve.plan``, ``serve.pack`` (host arrays and their upload),
+        ``serve.issue``, ``serve.await`` (the program's await, which need
+        not wait for the device), ``serve.fetch`` (the logits to the host:
+        waits for the step to end, then copies), ``serve.sample``,
+        ``serve.commit``."""
+        with TraceAnnotation("serve.plan"):
+            if self.ctx.fault_clock is not None:
+                self.ctx.fault_clock.advance(self._ticks)
+            self._ticks += 1
+            plan = self.sched.plan_tick()
         if not plan.rows:
             return 0
-        t_b = self._bucket(plan.n_rows)
-        tokens = np.zeros(t_b, np.int32)
-        positions = np.zeros(t_b, np.int32)
-        row_req = np.full(t_b, -1, np.int32)
-        for i, (row, pos, tok) in enumerate(plan.rows):
-            tokens[i] = tok
-            positions[i] = pos
-            row_req[i] = row
-        sample_rows = np.zeros(self.scfg.max_requests, np.int32)
-        for row, idx in plan.sample_rows.items():
-            sample_rows[row] = idx
+        with TraceAnnotation("serve.pack"):
+            t_b = self._bucket(plan.n_rows)
+            tokens = np.zeros(t_b, np.int32)
+            positions = np.zeros(t_b, np.int32)
+            row_req = np.full(t_b, -1, np.int32)
+            for i, (row, pos, tok) in enumerate(plan.rows):
+                tokens[i] = tok
+                positions[i] = pos
+                row_req[i] = row
+            sample_rows = np.zeros(self.scfg.max_requests, np.int32)
+            for row, idx in plan.sample_rows.items():
+                sample_rows[row] = idx
+            args = [jnp.asarray(a) for a in (tokens, positions, row_req,
+                                             self.kv.tables, sample_rows)]
         # issue/await lifecycle (DESIGN.md §11): the packed step's decode
         # collectives are in flight while the host finishes the tick
-        self._program.issue(
-            self.p, self.pool, jnp.asarray(tokens), jnp.asarray(positions),
-            jnp.asarray(row_req), jnp.asarray(self.kv.tables),
-            jnp.asarray(sample_rows), shape_key=t_b)
-        logits, self.pool = self._program.await_all()[-1]
-        logits = np.asarray(logits)
-        sampled = {}
-        for row in plan.sample_rows:
-            req = self.sched.active[row]
-            sampled[row] = self._sample(logits[row], req.temperature)
-        for req in self.sched.commit(plan, sampled):
-            self._finished[req.rid] = req.out
-        self._steps += 1
-        self._real_rows += plan.n_rows
-        self._padded_rows += t_b - plan.n_rows
-        self._peak_rows = max(self._peak_rows, plan.n_rows)
-        self._last_rows = plan.n_rows
-        self._bucket_steps[t_b] = self._bucket_steps.get(t_b, 0) + 1
+        with TraceAnnotation("serve.issue"):
+            self._program.issue(self.p, self.pool, *args, shape_key=t_b)
+        with TraceAnnotation("serve.await"):
+            logits, self.pool = self._program.await_all()[-1]
+        with TraceAnnotation("serve.fetch"):
+            logits = np.asarray(logits)
+        with TraceAnnotation("serve.sample"):
+            sampled = {}
+            for row in plan.sample_rows:
+                req = self.sched.active[row]
+                sampled[row] = self._sample(logits[row], req.temperature)
+        with TraceAnnotation("serve.commit"):
+            for req in self.sched.commit(plan, sampled):
+                self._finished[req.rid] = req.out
+            self._steps += 1
+            self._real_rows += plan.n_rows
+            self._padded_rows += t_b - plan.n_rows
+            self._peak_rows = max(self._peak_rows, plan.n_rows)
+            self._bucket_steps[t_b] = self._bucket_steps.get(t_b, 0) + 1
         return plan.n_rows
 
     def run_until_drained(self, max_ticks: int = 10000) -> None:
@@ -427,7 +472,6 @@ class PagedServeEngine:
             "tokens_in_flight": {
                 "budget": self.scfg.max_tokens_in_flight,
                 "peak": self._peak_rows,
-                "last": self._last_rows,
             },
             "rows": {"real": self._real_rows, "padded": self._padded_rows},
             "buckets": {str(b): n

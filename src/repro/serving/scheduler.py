@@ -33,7 +33,10 @@ from __future__ import annotations
 
 import collections
 import dataclasses
-from typing import Deque, Dict, List, Optional, Tuple
+import time
+from typing import Callable, Deque, Dict, List, Optional, Tuple
+
+import numpy as np
 
 from repro.serving.paged_kv import NoFreeBlocks, PagedKVCache
 
@@ -52,6 +55,14 @@ class PagedRequest:
     #: admission sequence number — eviction victims are picked newest-first
     adm_seq: int = -1
     preemptions: int = 0
+    #: the scheduler's clock at the FIRST admission (a re-admission after
+    #: preemption keeps it) and at the first token
+    t_admit: Optional[float] = None
+    t_first: Optional[float] = None
+    #: ticks from admission to the first token in which the request was
+    #: packed, and in which it was admitted but got no row
+    prefill_ticks: int = 0
+    stall_ticks: int = 0
 
     @property
     def seq(self) -> List[int]:
@@ -78,11 +89,17 @@ class TickPlan:
 
 
 class ContinuousScheduler:
+    """``clock`` stamps each request's first admission and first token
+    (injectable, like StepProgram's); ``report()["prefill"]`` sums them
+    over the requests that have a first token."""
+
     def __init__(self, cache: PagedKVCache, *, max_requests: int,
-                 max_tokens_in_flight: int, eos_id: int = -1):
+                 max_tokens_in_flight: int, eos_id: int = -1,
+                 clock: Callable[[], float] = time.perf_counter):
         assert max_requests <= max_tokens_in_flight, \
             "every decode row must fit one tick"
         self.cache = cache
+        self.clock = clock
         self.max_requests = max_requests
         self.max_tokens_in_flight = max_tokens_in_flight
         self.eos_id = eos_id
@@ -93,6 +110,9 @@ class ContinuousScheduler:
         self.admitted = 0
         self.retired = 0
         self.preemptions = 0
+        self._prefill_s: List[float] = []   # first token - first admission
+        self._prefill_ticks = 0
+        self._stall_ticks = 0
 
     # -- client ----------------------------------------------------------------
 
@@ -130,6 +150,8 @@ class ContinuousScheduler:
             req.done = 0
             req.adm_seq = self._adm_seq
             self._adm_seq += 1
+            if req.t_admit is None:
+                req.t_admit = self.clock()
             self.active[row] = req
             self.admitted += 1
 
@@ -213,6 +235,13 @@ class ContinuousScheduler:
                 rows.append((req.row, pos, seq[pos]))
             packed_rows.add(req.row)
             budget -= n
+
+        for req in order:
+            if req.row >= 0 and req.t_first is None:
+                if req.row in packed_rows:
+                    req.prefill_ticks += 1
+                else:
+                    req.stall_ticks += 1
         return TickPlan(rows, sample_rows)
 
     # -- commit ----------------------------------------------------------------
@@ -233,6 +262,11 @@ class ContinuousScheduler:
             req = self.active[row]
             assert req is not None and plan.sample_rows.get(row) is not None
             req.out.append(tok)
+            if req.t_first is None:
+                req.t_first = self.clock()
+                self._prefill_s.append(req.t_first - req.t_admit)
+                self._prefill_ticks += req.prefill_ticks
+                self._stall_ticks += req.stall_ticks
             if len(req.out) >= req.max_new or tok == self.eos_id:
                 self.cache.release(row)
                 self.active[row] = None
@@ -242,10 +276,20 @@ class ContinuousScheduler:
         return finished
 
     def report(self) -> Dict[str, object]:
+        ticks = self._prefill_ticks + self._stall_ticks
         return {
             "admitted": self.admitted,
             "retired": self.retired,
             "preemptions": self.preemptions,
             "waiting": len(self.queue),
             "in_flight": self.in_flight(),
+            # first admission to first token, over the requests that have
+            # one: its 80th percentile, and the share of those ticks in
+            # which the request was admitted but got no row
+            "prefill": {
+                "requests": len(self._prefill_s),
+                "p80_ms": float(np.percentile(self._prefill_s, 80)) * 1e3
+                if self._prefill_s else None,
+                "stall_share": self._stall_ticks / ticks if ticks else None,
+            },
         }

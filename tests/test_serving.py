@@ -313,3 +313,130 @@ def test_step_program_shape_key_buckets():
     assert rep["shape_buckets"] == [4, 8]
     assert rep["executable_cache"]["rebuilds"] == 2
     assert rep["executable_cache"]["hits"] == 1
+
+
+# ---------------------------------------------------------------------------
+# spans, named scopes and per-request stamps (the profiler's view)
+# ---------------------------------------------------------------------------
+
+TICK_SPANS = ["serve.plan", "serve.pack", "serve.issue", "serve.await",
+              "serve.fetch", "serve.sample", "serve.commit"]
+
+
+def test_tick_emits_its_phase_spans_back_to_back(setup, tmp_path):
+    """Under a profiler session one tick writes the seven ``serve.*``
+    spans once each, in order, each starting where the last ended (to
+    within a span's own overhead)."""
+    import glob
+
+    cfg, params = setup
+    eng = PagedServeEngine(params, cfg, single_device_ctx(),
+                           PagedServeConfig(max_requests=2, cache_len=32,
+                                            kv_block=8,
+                                            max_tokens_in_flight=8,
+                                            min_bucket=8))
+    eng.submit(_prompts([5])[0], max_new=2)
+    eng.tick()                                # compiles outside the trace
+    with jax.profiler.trace(str(tmp_path)):
+        eng.tick()
+    eng.close()
+    path = glob.glob(f"{tmp_path}/**/*.xplane.pb", recursive=True)[0]
+    pd = jax.profiler.ProfileData.from_file(path)
+    spans = sorted((ev.start_ns, ev.start_ns + ev.duration_ns, ev.name)
+                   for plane in pd.planes if plane.name == "/host:CPU"
+                   for line in plane.lines for ev in line.events
+                   if ev.name.startswith("serve."))
+    assert [name for _, _, name in spans] == TICK_SPANS
+    for (_, end, _), (start, _, _) in zip(spans, spans[1:]):
+        assert 0 <= start - end < 2e6          # ns: no overlap, no gap
+
+
+def test_scheduler_stamps_admission_stall_and_first_token():
+    """Two 12-token prompts under an 8-row budget: the second waits a
+    tick for a row (a stall), is preempted when the first outgrows a
+    seven-block pool, and keeps the time of its first admission; the
+    scheduler's report sums the stamps."""
+    import itertools
+
+    from repro.serving.scheduler import ContinuousScheduler, PagedRequest
+
+    ticks = itertools.count()
+    sched = ContinuousScheduler(PagedKVCache(7, 4, 8, 2), max_requests=2,
+                                max_tokens_in_flight=8,
+                                clock=lambda: float(next(ticks)))
+    first = PagedRequest(0, list(range(1, 13)), max_new=16)
+    second = PagedRequest(1, list(range(1, 13)), max_new=4)
+    sched.submit(first)
+    sched.submit(second)
+    assert sched.report()["prefill"] == {"requests": 0, "p80_ms": None,
+                                         "stall_share": None}
+    admitted = []
+    while sched.has_work():
+        plan = sched.plan_tick()
+        admitted.append(second.t_admit)
+        sched.commit(plan, {row: 7 for row in plan.sample_rows})
+    assert second.preemptions == 1
+    assert set(admitted) == {second.t_admit}
+    assert second.stall_ticks >= 1 and second.prefill_ticks >= 1
+    assert first.stall_ticks == 0 and first.prefill_ticks == 2
+    for req in (first, second):
+        assert req.t_admit <= req.t_first
+    waits = [1e3 * (r.t_first - r.t_admit) for r in (first, second)]
+    stalls = second.stall_ticks
+    rep = sched.report()["prefill"]
+    assert rep["requests"] == 2
+    assert rep["p80_ms"] == pytest.approx(np.percentile(waits, 80))
+    assert rep["stall_share"] == pytest.approx(
+        stalls / (stalls + first.prefill_ticks + second.prefill_ticks))
+
+
+def test_serve_launcher_prints_prefill_stamps(capsys):
+    """``launch/serve.py`` reads the scheduler's stamps: the p80 of first
+    admission to first token and the share of stalled ticks."""
+    from repro.launch.serve import main
+
+    assert main(["--smoke", "--paged", "on", "--requests", "3",
+                 "--max-new", "2", "--max-tokens-in-flight", "8",
+                 "--max-requests", "2"]) == 0
+    line = next(ln for ln in capsys.readouterr().out.splitlines()
+                if "admission to first token" in ln)
+    assert "over 3 requests" in line and "% of those ticks" in line
+
+
+def test_paged_step_hlo_names_its_scopes(setup):
+    """``paged_step_texts`` lowers the very module the engine runs (the
+    device-trace reduction reads it, ``chipbench/scopes.py``), which
+    keeps its module name and the named scopes."""
+    import re
+
+    from repro.serving.engine import paged_step_texts
+
+    cfg, params = setup
+    scfg = PagedServeConfig(max_requests=2, cache_len=32, kv_block=8,
+                            max_tokens_in_flight=8, min_bucket=4)
+    eng = PagedServeEngine(params, cfg, single_device_ctx(), scfg)
+    issued = []
+    real_issue = eng._program.issue
+    eng._program.issue = lambda *a, **k: (issued.append(a),
+                                          real_issue(*a, **k))[1]
+    eng.submit(_prompts([6])[0], max_new=1)
+    eng.tick()
+    eng.close()
+    abstract = jax.tree.map(lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype),
+                            issued[0])
+    ran = eng._step_builder().lower(*abstract).compile().as_text()
+    texts = paged_step_texts(cfg, single_device_ctx(), scfg, params)
+    assert len(texts) == len(scfg.buckets()) == 2
+
+    def program(text):            # instructions and scopes; no call stacks
+        lines = [ln.strip() for ln in text.splitlines() if " = " in ln]
+        return ([re.sub(r", metadata=\{[^}]*\}", "", ln) for ln in lines],
+                re.findall(r'op_name="([^"]*)"', text))
+    assert program(texts[scfg.buckets().index(8)]) == program(ran)
+    for text in texts:
+        assert text.startswith("HloModule jit_paged_step")
+        names = set(re.findall(r'op_name="([^"]*)"', text))
+        for scope in ("/embed/", "/layers/", "/qkv_proj/", "/attn/kv_write/",
+                      "/attn/kv_gather/", "/attn/attend/", "/o_proj/",
+                      "/mlp/", "/head/"):
+            assert any(scope in n for n in names), scope
