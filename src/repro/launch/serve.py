@@ -205,6 +205,10 @@ def main(argv=None) -> int:
             print(f"serving: admission to first token p80 "
                   f"{pf['p80_ms']:.1f} ms over {pf['requests']} requests, "
                   f"no row in {100 * pf['stall_share']:.1f}% of those ticks")
+        ch = srv["attn_chunks"]
+        if ch["span"]:
+            print(f"serving: attention ran {ch['run']} of {ch['span']} "
+                  f"K/V chunks ({100 * ch['run'] / ch['span']:.1f}%)")
     if clock is not None:
         fr = clock.report()
         print(f"faults: {len(fr['transitions'])} transition(s), "

@@ -89,6 +89,62 @@ def _mask(q_pos: jax.Array, k_pos: jax.Array, causal: bool,
     return m
 
 
+def _softmax_chunk(carry, qg, q_pos, ci, kci, vci, *, chunk: int, k_offset,
+                   causal: bool, window: Optional[int], kv_valid,
+                   local_len: Optional[int]):
+    """One chunk's streaming-softmax update, the body that
+    :func:`chunked_attention` scans and :func:`paged_attention` loops.
+
+    carry: (running max, denominator, accumulator) of [B,Hkv,g,Sq(,hd)];
+    qg: scaled f32 queries [B,Sq,Hkv,g,hd]; ci: chunk index; kci, vci:
+    chunk ``ci`` of the keys and values [B,chunk,Hkv,hd], keys at local
+    positions ci*chunk + j (global k_offset + local).  A chunk every row
+    masks wholly leaves a finite running max as it is (alpha = 1) and adds
+    exact zeros."""
+    m_run, l_run, acc = carry
+    k_local = ci * chunk + jnp.arange(chunk)
+    k_pos = k_offset + k_local
+    kf = kci.astype(jnp.float32)
+    vf = vci.astype(jnp.float32)
+    s = jnp.einsum("bqhgd,bchd->bhgqc", qg, kf)      # [B,Hkv,g,Sq,chunk]
+    keep = _mask(q_pos, k_pos, causal, window, kv_valid)
+    if local_len is not None:
+        keep = keep & (k_local < local_len)
+    if keep.ndim == 2:                           # [Sq, chunk]
+        keep = keep[None, None, None]
+    else:                                        # [B, Sq, chunk]
+        keep = keep[:, None, None]
+    s = jnp.where(keep, s, -jnp.inf)
+    m_new = jnp.maximum(m_run, s.max(axis=-1))       # [B,Hkv,g,Sq]
+    # guard all-masked rows (m == -inf): exp(-inf - -inf) -> use where
+    m_safe = jnp.where(jnp.isfinite(m_new), m_new, 0.0)
+    p = jnp.exp(jnp.where(jnp.isfinite(s), s - m_safe[..., None], -jnp.inf))
+    p = jnp.where(jnp.isfinite(s), p, 0.0)
+    alpha = jnp.where(jnp.isfinite(m_run),
+                      jnp.exp(m_run - m_safe), 0.0)  # rescale old
+    l_new = l_run * alpha + p.sum(axis=-1)
+    acc_new = acc * alpha[..., None] + jnp.einsum(
+        "bhgqc,bchd->bhgqd", p, vf)
+    return m_new, l_new, acc_new
+
+
+def _softmax_init(b: int, hkv: int, group: int, sq: int, hd: int):
+    """The empty (running max, denominator, accumulator)."""
+    m0 = jnp.full((b, hkv, group, sq), -jnp.inf, jnp.float32)
+    l0 = jnp.zeros((b, hkv, group, sq), jnp.float32)
+    a0 = jnp.zeros((b, hkv, group, sq, hd), jnp.float32)
+    return m0, l0, a0
+
+
+def _softmax_out(acc: jax.Array, l_run: jax.Array, dtype) -> jax.Array:
+    """Normalize the accumulator [B,Hkv,g,Sq,hd] into [B,Sq,Hq,hd]."""
+    b, hkv, group, sq, hd = acc.shape
+    denom = jnp.maximum(l_run, 1e-30)
+    out = acc / denom[..., None]                          # [B,Hkv,g,Sq,hd]
+    out = out.transpose(0, 3, 1, 2, 4).reshape(b, sq, hkv * group, hd)
+    return out.astype(dtype)
+
+
 def chunked_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
                       causal: bool, window: Optional[int] = None,
                       q_offset=0, k_offset=0,
@@ -128,47 +184,21 @@ def chunked_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
 
     def step(carry, xs):
         ci, kci, vci = xs                                # kci: [B,chunk,Hkv,hd]
-        m_run, l_run, acc = carry
-        k_local = ci * chunk + jnp.arange(chunk)
-        k_pos = k_offset + k_local
-        kf = kci.astype(jnp.float32)
-        vf = vci.astype(jnp.float32)
-        s = jnp.einsum("bqhgd,bchd->bhgqc", qg, kf)      # [B,Hkv,g,Sq,chunk]
-        keep = _mask(q_pos, k_pos, causal, window, kv_valid)
-        if local_len is not None:
-            keep = keep & (k_local < local_len)
-        if keep.ndim == 2:                       # [Sq, chunk]
-            keep = keep[None, None, None]
-        else:                                    # [B, Sq, chunk]
-            keep = keep[:, None, None]
-        s = jnp.where(keep, s, -jnp.inf)
-        m_new = jnp.maximum(m_run, s.max(axis=-1))       # [B,Hkv,g,Sq]
-        # guard all-masked rows (m == -inf): exp(-inf - -inf) -> use where
-        m_safe = jnp.where(jnp.isfinite(m_new), m_new, 0.0)
-        p = jnp.exp(jnp.where(jnp.isfinite(s), s - m_safe[..., None], -jnp.inf))
-        p = jnp.where(jnp.isfinite(s), p, 0.0)
-        alpha = jnp.where(jnp.isfinite(m_run),
-                          jnp.exp(m_run - m_safe), 0.0)  # rescale old
-        l_new = l_run * alpha + p.sum(axis=-1)
-        acc_new = acc * alpha[..., None] + jnp.einsum(
-            "bhgqc,bchd->bhgqd", p, vf)
-        return (m_new, l_new, acc_new), None
+        return _softmax_chunk(carry, qg, q_pos, ci, kci, vci, chunk=chunk,
+                              k_offset=k_offset, causal=causal,
+                              window=window, kv_valid=kv_valid,
+                              local_len=local_len), None
 
-    m0 = jnp.full((b, hkv, group, sq), -jnp.inf, jnp.float32)
-    l0 = jnp.zeros((b, hkv, group, sq), jnp.float32)
-    a0 = jnp.zeros((b, hkv, group, sq, hd), jnp.float32)
+    init = _softmax_init(b, hkv, group, sq, hd)
     idx = jnp.arange(n_chunks)
     (m_f, l_f, acc_f), _ = lax.scan(
-        step, (m0, l0, a0),
+        step, init,
         (idx, jnp.moveaxis(kc, 1, 0), jnp.moveaxis(vc, 1, 0)))
 
     if with_stats:
         # caller merges across shards (lse_merge) before normalizing
         return acc_f, m_f, l_f
-    denom = jnp.maximum(l_f, 1e-30)
-    out = acc_f / denom[..., None]                        # [B,Hkv,g,Sq,hd]
-    out = out.transpose(0, 3, 1, 2, 4).reshape(b, sq, hq, hd)
-    return out.astype(q.dtype)
+    return _softmax_out(acc_f, l_f, q.dtype)
 
 
 def lse_merge(parts):
@@ -438,6 +468,78 @@ def _seq_sharded_decode(q, k_new, v_new, kv_cache, cache_pos, cfg, ctx,
 # paged attention (continuous-batching serving, DESIGN.md §13)
 # ---------------------------------------------------------------------------
 
+def paged_attention(q: jax.Array, k_pool: jax.Array, v_pool: jax.Array,
+                    block_tables: jax.Array, *, positions: jax.Array,
+                    kv_valid: jax.Array,
+                    window: Optional[int] = None) -> jax.Array:
+    """Streaming-softmax attention of packed single-token rows over a
+    paged pool, bit-identical to gathering each row's whole view (index i
+    of it is position i) and calling :func:`chunked_attention` on it.
+
+    q: [T, 1, Hq, hd]; k_pool, v_pool: [n_blocks, block, Hkv, hd];
+    block_tables: [T, max_blocks]; positions / kv_valid: [T].
+
+    Iteration c gathers chunk c of every row straight from the pool and
+    applies chunked_attention's update to it, so no whole view is ever
+    built.  Where ``block`` divides ``chunk`` the gather takes whole
+    blocks (``chunk // block`` per row); otherwise it takes position rows.
+    Table slots past ``max_blocks`` read block 0 and are masked by local
+    index, as chunked_attention masks its padding.  The loop stops after
+    the last chunk that holds a position below some row's ``kv_valid``:
+    the chunks beyond are masked for every row and would change no bit.
+    An all-padding step runs no iteration and returns exact zeros.
+
+    Named scopes: ``kv_gather`` on the in-loop gather, ``attend`` on the
+    math."""
+    t, sq, hq, hd = q.shape
+    nb, bs, hkv, _ = k_pool.shape
+    group = hq // hkv
+    chunk = ATTN_CHUNK                  # chunked_attention's, bit for bit
+    maxb = block_tables.shape[1]
+    span = maxb * bs
+    n_chunks = -(-span // chunk)
+    local_len = span if n_chunks * chunk != span else None
+    with jax.named_scope("attend"):
+        qf = q.astype(jnp.float32) * (1.0 / math.sqrt(hd))
+        q_pos = positions[:, None] + jnp.arange(sq)
+        qg = qf.reshape(t, sq, hkv, group, hd)           # [T,Sq,Hkv,g,hd]
+        init = _softmax_init(t, hkv, group, sq, hd)
+    with jax.named_scope("kv_gather"):
+        if chunk % bs == 0:
+            per = chunk // bs                   # whole blocks per chunk
+            tables = jnp.pad(block_tables,
+                             ((0, 0), (0, n_chunks * per - maxb)))
+
+            def gather(pool, c):
+                ids = lax.dynamic_slice_in_dim(tables, c * per, per, axis=1)
+                return pool[ids].reshape(t, chunk, hkv, hd)
+        else:
+            n_tab = -(-n_chunks * chunk // bs)
+            tables = jnp.pad(block_tables, ((0, 0), (0, n_tab - maxb)))
+            k_pool = k_pool.reshape(nb * bs, hkv, hd)
+            v_pool = v_pool.reshape(nb * bs, hkv, hd)
+
+            def gather(pool, c):
+                k_local = c * chunk + jnp.arange(chunk)
+                ids = jnp.take(tables, k_local // bs, axis=1)  # [T, chunk]
+                return pool[ids * bs + k_local % bs]
+    # the chunks up to the last that holds a live position of some row
+    n_live = jnp.minimum((jnp.max(kv_valid) + chunk - 1) // chunk, n_chunks)
+
+    def body(c, carry):
+        with jax.named_scope("kv_gather"):
+            kci = gather(k_pool, c)                      # [T,chunk,Hkv,hd]
+            vci = gather(v_pool, c)
+        with jax.named_scope("attend"):
+            return _softmax_chunk(carry, qg, q_pos, c, kci, vci, chunk=chunk,
+                                  k_offset=0, causal=True, window=window,
+                                  kv_valid=kv_valid, local_len=local_len)
+
+    _, l_f, acc_f = lax.fori_loop(0, n_live, body, init)
+    with jax.named_scope("attend"):
+        return _softmax_out(acc_f, l_f, q.dtype)
+
+
 def paged_attention_block(p, x: jax.Array, cfg: ArchConfig,
                           ctx: ParallelCtx, *, positions: jax.Array,
                           kv_valid: jax.Array, pools, block_tables,
@@ -455,8 +557,9 @@ def paged_attention_block(p, x: jax.Array, cfg: ArchConfig,
                    layer's physical block pool
     block_tables : [T, max_blocks] int32 — per-ROW tables (the engine
                    gathers its per-request tables out to packed rows)
-    impl         : "reference" (dense block-gather + chunked_attention — the
-                   oracle, bit-identical to the wave engine's dense-cache
+    impl         : "reference" (:func:`paged_attention`: chunked_attention's
+                   streaming softmax with each chunk gathered from the
+                   pool, bit-identical to the wave engine's dense-cache
                    path) or "kernel" (kernels/flash_decode.py)
 
     The new K/V are scattered into the pool BEFORE attention, so later
@@ -521,22 +624,9 @@ def paged_attention_block(p, x: jax.Array, cfg: ArchConfig,
                                            new_pools[1], block_tables,
                                            kv_valid, window=window)[:, None]
         else:
-            # dense block-gather reference: index i of the gathered view
-            # IS position i, so this call matches the wave engine's
-            # dense-cache chunked_attention bit for bit (same chunking,
-            # same masks; stale lanes beyond kv_valid contribute exact
-            # zeros either way).
-            with jax.named_scope("kv_gather"):
-                maxb = block_tables.shape[1]
-                s_len = maxb * bs_blk
-                src = (block_tables[:, :, None] * bs_blk +
-                       jnp.arange(bs_blk)[None, None, :]).reshape(b, s_len)
-                kg = kp_flat[src]                 # [T, S, kv_w, hd]
-                vg = vp_flat[src]
-            with jax.named_scope("attend"):
-                out = chunked_attention(q, kg, vg, causal=True,
-                                        window=window, q_offset=positions,
-                                        kv_valid=kv_valid)
+            out = paged_attention(q, *new_pools, block_tables,
+                                  positions=positions, kv_valid=kv_valid,
+                                  window=window)
 
     with jax.named_scope("o_proj"):
         o = jnp.einsum("bsf,fd->bsd", out.reshape(b, s, hq_l * hd), p["wo"])

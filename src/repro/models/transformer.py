@@ -500,8 +500,9 @@ class PagedConfig:
     n_blocks           : physical blocks in the pool (per layer)
     max_blocks_per_req : logical blocks per request row
                          (= ceil(request length cap / block_size))
-    attn_impl          : "reference" (dense block-gather, bit-identical to
-                         the wave path) | "kernel" (flash_decode Pallas)
+    attn_impl          : "reference" (chunked K/V gather in the streaming
+                         softmax, bit-identical to the wave path) |
+                         "kernel" (flash_decode Pallas)
     window_override    : "cfg" or an int/None, as DecodeConfig
     """
     block_size: int = 16

@@ -37,6 +37,7 @@ import numpy as np
 from jax.profiler import TraceAnnotation
 
 from repro.models.config import ArchConfig
+from repro.models.layers import ATTN_CHUNK
 from repro.models.tp import ParallelCtx
 from repro.models.transformer import (DecodeConfig, PagedConfig,
                                       decode_step, init_cache,
@@ -322,13 +323,15 @@ class PagedServeEngine:
     position.
 
     Greedy token streams are bit-identical to :class:`ServeEngine` for
-    the same admitted set (the correctness contract): the dense
-    block-gather reference path feeds chunked_attention the exact operands
-    the wave path does, and preemption/resume re-prefills ``prompt + out``
-    teacher-forced, reproducing the evicted K/V exactly.  Requires
-    ``ceil(gather_span/512) == ceil(cache_len/512)`` so both paths chunk
-    identically — true whenever cache_len is a multiple of kv_block, and
-    of everything <= 512 otherwise rounded within the same chunk.
+    the same admitted set (the correctness contract): the reference path
+    gathers each 512-position chunk from the pool and applies
+    chunked_attention's own per-chunk update to the operands the wave
+    path sees (layers.paged_attention), and preemption/resume re-prefills
+    ``prompt + out`` teacher-forced, reproducing the evicted K/V exactly.
+    Requires ``ceil(gather_span/512) == ceil(cache_len/512)`` so both
+    paths chunk identically — true whenever cache_len is a multiple of
+    kv_block, and of everything <= 512 otherwise rounded within the same
+    chunk.
     """
 
     def __init__(self, params, cfg: ArchConfig, ctx: ParallelCtx,
@@ -359,6 +362,9 @@ class PagedServeEngine:
         self._padded_rows = 0
         self._peak_rows = 0
         self._bucket_steps: Dict[int, int] = {}
+        # K/V chunks the reference attention ran, and those of the span
+        self._chunks_run = 0
+        self._chunks_span = 0
 
     def _step_builder(self):
         """A fresh jit wrapper per build; the shape_key bucket keeps each
@@ -452,6 +458,13 @@ class PagedServeEngine:
             self._padded_rows += t_b - plan.n_rows
             self._peak_rows = max(self._peak_rows, plan.n_rows)
             self._bucket_steps[t_b] = self._bucket_steps.get(t_b, 0) + 1
+            if self.pcfg.attn_impl == "reference":
+                # the step stops at the chunk of its deepest row
+                # (layers.paged_attention); padding rows sit at position 0
+                self._chunks_run += -(-(int(positions.max()) + 1)
+                                      // ATTN_CHUNK)
+                self._chunks_span += -(-self.pcfg.max_blocks_per_req
+                                       * self.pcfg.block_size // ATTN_CHUNK)
         return plan.n_rows
 
     def run_until_drained(self, max_ticks: int = 10000) -> None:
@@ -463,6 +476,10 @@ class PagedServeEngine:
     # -- reporting / lifecycle ------------------------------------------------
 
     def serving_report(self) -> Dict[str, object]:
+        """The engine's counts since it was built.  ``attn_chunks``: the
+        512-position K/V chunks the reference attention ran (``run``, up
+        to each step's deepest row) against those of the whole block-table
+        span (``span``), summed over steps; zero under the kernel."""
         ec = self._program.cache.report()
         lookups = ec["hits"] + ec["rebuilds"]
         return {
@@ -483,6 +500,8 @@ class PagedServeEngine:
             },
             "scheduler": self.sched.report(),
             "kv_blocks": self.kv.report(),
+            "attn_chunks": {"run": self._chunks_run,
+                            "span": self._chunks_span},
         }
 
     def comm_report(self) -> Dict[str, object]:

@@ -392,15 +392,18 @@ def test_scheduler_stamps_admission_stall_and_first_token():
 
 def test_serve_launcher_prints_prefill_stamps(capsys):
     """``launch/serve.py`` reads the scheduler's stamps: the p80 of first
-    admission to first token and the share of stalled ticks."""
+    admission to first token and the share of stalled ticks; and the
+    engine's count of the K/V chunks attention ran."""
     from repro.launch.serve import main
 
     assert main(["--smoke", "--paged", "on", "--requests", "3",
                  "--max-new", "2", "--max-tokens-in-flight", "8",
                  "--max-requests", "2"]) == 0
-    line = next(ln for ln in capsys.readouterr().out.splitlines()
-                if "admission to first token" in ln)
+    out = capsys.readouterr().out.splitlines()
+    line = next(ln for ln in out if "admission to first token" in ln)
     assert "over 3 requests" in line and "% of those ticks" in line
+    line = next(ln for ln in out if "K/V chunks" in ln)
+    assert line.endswith("(100.0%)")     # smoke prompts fit one chunk
 
 
 def test_paged_step_hlo_names_its_scopes(setup):
@@ -440,3 +443,112 @@ def test_paged_step_hlo_names_its_scopes(setup):
                       "/attn/kv_gather/", "/attn/attend/", "/o_proj/",
                       "/mlp/", "/head/"):
             assert any(scope in n for n in names), scope
+
+
+# ---------------------------------------------------------------------------
+# streaming paged attention: chunks gathered in the loop, live bound
+# ---------------------------------------------------------------------------
+
+def _dense_gather_attention(q, kp, vp, tables, positions, kv_valid):
+    """The former reference path: gather every row's whole view (index i
+    is position i), then chunked_attention over it."""
+    from repro.models.layers import chunked_attention
+    nb, bs, hkv, hd = kp.shape
+    t, maxb = tables.shape
+    src = (tables[:, :, None] * bs + jnp.arange(bs)).reshape(t, maxb * bs)
+    kg = kp.reshape(nb * bs, hkv, hd)[src]
+    vg = vp.reshape(nb * bs, hkv, hd)[src]
+    return chunked_attention(q, kg, vg, causal=True, q_offset=positions,
+                             kv_valid=kv_valid)
+
+
+@pytest.mark.parametrize("bs,maxb,deepest", [
+    (16, 96, 300),        # the deepest row ends in the first of 3 chunks
+    (16, 96, 900),        # in the middle chunk
+    (16, 96, 1536),       # in the last chunk
+    (16, 96, 0),          # an all-padding step: no iteration at all
+    (16, 40, 640),        # span 640: a partial last chunk
+    (24, 50, 1100),       # blocks of 24 do not divide 512: position rows
+], ids=["first", "middle", "last", "all_padding", "partial_last",
+        "block_24"])
+def test_streaming_paged_attention_bit_identical(bs, maxb, deepest):
+    """The streaming reference path equals the dense gather plus
+    chunked_attention bit for bit (bf16, GQA group 8), wherever the
+    step's deepest row ends: the chunks past it, which the loop skips,
+    would have added exact zeros."""
+    from repro.models.layers import paged_attention
+
+    t, hq, hkv, hd, nb = 8, 16, 2, 64, 120
+    k = jax.random.split(jax.random.PRNGKey(bs * maxb + deepest), 5)
+    q = jax.random.normal(k[0], (t, 1, hq, hd)).astype(jnp.bfloat16)
+    kp = jax.random.normal(k[1], (nb, bs, hkv, hd)).astype(jnp.bfloat16)
+    vp = jax.random.normal(k[2], (nb, bs, hkv, hd)).astype(jnp.bfloat16)
+    tables = jax.random.randint(k[3], (t, maxb), 0, nb, jnp.int32)
+    kv_valid = jax.random.randint(k[4], (t,), 1, max(deepest, 1) + 1,
+                                  jnp.int32)
+    kv_valid = kv_valid.at[0].set(deepest).at[-1].set(0)    # one pad row
+    if deepest == 0:
+        kv_valid = jnp.zeros_like(kv_valid)
+    positions = jnp.maximum(kv_valid - 1, 0)
+    got = jax.jit(paged_attention)(q, kp, vp, tables, positions=positions,
+                                   kv_valid=kv_valid)
+    want = jax.jit(_dense_gather_attention)(q, kp, vp, tables, positions,
+                                            kv_valid)
+    assert got.dtype == want.dtype == jnp.bfloat16
+    assert jnp.array_equal(got, want)
+    assert np.all(np.asarray(got[-1], np.float32) == 0.0)
+    if deepest == 0:
+        assert np.all(np.asarray(got, np.float32) == 0.0)
+
+
+def test_paged_step_holds_no_whole_kv_view(setup):
+    """At the top bucket the compiled step never builds the rows' whole
+    ``[T, max_blocks*block, kv_w, hd]`` K/V view, in any layout (flat,
+    cut into chunks, transposed): no array is that large.  It gathers
+    512-position chunks inside the loop, under ``attn/kv_gather``, with
+    the math under ``attn/attend``."""
+    import math
+    import re
+
+    from repro.serving.engine import paged_step_texts
+
+    cfg, params = setup
+    scfg = PagedServeConfig(max_requests=2, cache_len=1024, kv_block=16,
+                            max_tokens_in_flight=8, min_bucket=8)
+    text = paged_step_texts(cfg, single_device_ctx(), scfg, params)[-1]
+    t, span = scfg.buckets()[-1], 1024
+    kv_w, hd = cfg.n_kv_heads, cfg.head_dim_
+    shapes = set(re.findall(r"\w\[([\d,]+)\]", text))
+    sizes = [math.prod(int(d) for d in shp.split(",")) for shp in shapes]
+    assert max(sizes) < t * span * kv_w * hd
+    assert f"{t},512,{kv_w},{hd}" in shapes          # one chunk's view
+    names = set(re.findall(r'op_name="([^"]*)"', text))
+    for scope in ("/attn/while/body/kv_gather/", "/attn/while/body/attend/"):
+        assert any(scope in n for n in names), scope
+
+
+def test_serving_report_counts_attention_chunks(setup):
+    """``attn_chunks``: a 700-token prompt prefilled 256 rows a tick next
+    to a 40-token one; each step runs the 512-position chunks up to its
+    deepest row, out of the two of the 1024-position span."""
+    cfg, params = setup
+    eng = PagedServeEngine(params, cfg, single_device_ctx(),
+                           PagedServeConfig(max_requests=2, cache_len=1024,
+                                            kv_block=16,
+                                            max_tokens_in_flight=256,
+                                            min_bucket=8))
+    plans = []
+    real_plan = eng.sched.plan_tick
+    eng.sched.plan_tick = lambda: (plans.append(real_plan()), plans[-1])[1]
+    long, short = _prompts([700, 40], seed=8)
+    eng.submit(long, max_new=3)
+    eng.submit(short, max_new=2)
+    eng.run_until_drained()
+    rep = eng.serving_report()
+    eng.close()
+    deepest = [max(pos for _, pos, _ in p.rows) + 1 for p in plans if p.rows]
+    assert deepest == [256, 512, 700, 701, 702]
+    assert rep["steps"] == 5
+    assert rep["attn_chunks"] == {"run": sum(-(-d // 512) for d in deepest),
+                                  "span": 5 * 2}
+    assert rep["attn_chunks"]["run"] == 8
