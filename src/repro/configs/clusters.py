@@ -40,7 +40,8 @@ _BUILDERS: Dict[str, Callable[[], ClusterTopology]] = {
     "2pod2xh800_rail4": lambda: make_cluster(
         "h800", 2, nics_per_node=4, nic_gbit=400.0, pods=2,
         name="2pod2xh800_rail4"),
-    # the kimi_k2_1t_a32b expert-parallel multi-pod scenario: 4 pods x
+    # a kimi_k2_1t_a32b expert-parallel multi-pod scenario (its 384
+    # routed experts over the ep span, the dry-run's train shapes): 4 pods x
     # 4 nodes of H800 with 4x400Gb rails per node, 8x400Gb spine uplinks
     # per pod at 4:1 oversubscription — the simulated fabric the
     # pod_a2a benchmark prices rail-local dispatch against

@@ -88,6 +88,11 @@ def run_one(arch: str, shape_name: str, multi_pod: bool,
     per-slot wire table below shows what each path actually ships."""
     cfg = get_config(arch)
     shape = SH.SHAPES[shape_name]
+    if cfg.mla is not None and shape.kind == "decode":
+        raise ValueError(
+            f"{arch}: latent attention (MLA) has no dense K/V cache for the "
+            f"{shape_name} decode step; it decodes through PagedServeEngine "
+            f"and its latent paged pool (repro.launch.serve --paged on)")
     from repro.configs.clusters import resolve_cluster, resolve_faults
     cluster, nodes, cluster_pods = resolve_cluster(cluster_name, nodes,
                                                    cluster_pods)

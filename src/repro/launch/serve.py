@@ -209,6 +209,11 @@ def main(argv=None) -> int:
         if ch["span"]:
             print(f"serving: attention ran {ch['run']} of {ch['span']} "
                   f"K/V chunks ({100 * ch['run'] / ch['span']:.1f}%)")
+        if "moe" in srv:
+            moe = srv["moe"]
+            print(f"serving: {moe['held']} of {moe['assigned']} token-expert "
+                  f"pairs on the held experts, per expert "
+                  f"{moe['per_expert']}")
     if clock is not None:
         fr = clock.report()
         print(f"faults: {len(fr['transitions'])} transition(s), "

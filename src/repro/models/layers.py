@@ -21,9 +21,10 @@ from typing import Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax import lax
 
-from repro.models.config import ArchConfig
+from repro.models.config import ArchConfig, YarnConfig
 from repro.models.tp import ParallelCtx
 
 ATTN_CHUNK = 512  # KV-axis chunk for the streaming softmax
@@ -45,10 +46,41 @@ def rope_freqs(head_dim: int, theta: float) -> jax.Array:
                                        dtype=jnp.float32) / head_dim))
 
 
-def apply_rope(x: jax.Array, positions: jax.Array, theta: float) -> jax.Array:
-    """x: [B, S, H, hd]; positions: [S] or [B, S]."""
+def yarn_mscale(scale: float, mscale: float) -> float:
+    """YaRN's attention factor ``0.1 * mscale * ln(scale) + 1``."""
+    return 1.0 if scale <= 1 else 0.1 * mscale * math.log(scale) + 1.0
+
+
+def yarn_freqs(head_dim: int, theta: float, yarn: YarnConfig) -> np.ndarray:
+    """YaRN's blended inverse frequencies (DeepSeek-V3 model code):
+    ``theta^(-2i/d)`` below the correction dim of ``beta_fast``,
+    ``theta^(-2i/d) / factor`` above that of ``beta_slow``, a linear ramp
+    between (its top bumped by 0.001 where the two meet)."""
+    def corr_dim(rotations):
+        return (head_dim * math.log(yarn.original_max_position
+                                    / (rotations * 2 * math.pi))
+                / (2 * math.log(theta)))
+    low = max(math.floor(corr_dim(yarn.beta_fast)), 0)
+    high = min(math.ceil(corr_dim(yarn.beta_slow)), head_dim - 1)
+    if low == high:
+        high += 0.001
+    extra = 1.0 / theta ** (np.arange(0, head_dim, 2) / head_dim)
+    ramp = np.clip((np.arange(head_dim // 2) - low) / (high - low), 0, 1)
+    return (extra / yarn.factor * ramp + extra * (1 - ramp)).astype(
+        np.float32)
+
+
+def apply_rope(x: jax.Array, positions: jax.Array, theta: float,
+               yarn: Optional[YarnConfig] = None) -> jax.Array:
+    """x: [B, S, H, hd]; positions: [S] or [B, S].  The two halves of
+    each head rotate together.  With ``yarn`` the frequencies are
+    :func:`yarn_freqs` and cos and sin carry ``mscale(factor, mscale) /
+    mscale(factor, mscale_all_dim)``."""
     hd = x.shape[-1]
-    freqs = rope_freqs(hd, theta)                        # [hd/2]
+    if yarn is None:
+        freqs = rope_freqs(hd, theta)                    # [hd/2]
+    else:
+        freqs = jnp.asarray(yarn_freqs(hd, theta, yarn))
     if positions.ndim == 1:
         ang = positions[:, None].astype(jnp.float32) * freqs[None, :]
         ang = ang[None, :, None, :]                      # [1, S, 1, hd/2]
@@ -56,6 +88,11 @@ def apply_rope(x: jax.Array, positions: jax.Array, theta: float) -> jax.Array:
         ang = positions[..., None].astype(jnp.float32) * freqs
         ang = ang[:, :, None, :]                         # [B, S, 1, hd/2]
     cos, sin = jnp.cos(ang), jnp.sin(ang)
+    if yarn is not None:
+        m = (yarn_mscale(yarn.factor, yarn.mscale)
+             / yarn_mscale(yarn.factor, yarn.mscale_all_dim))
+        if m != 1.0:
+            cos, sin = cos * m, sin * m
     x1, x2 = jnp.split(x.astype(jnp.float32), 2, axis=-1)
     out = jnp.concatenate([x1 * cos - x2 * sin, x1 * sin + x2 * cos], axis=-1)
     return out.astype(x.dtype)
@@ -95,9 +132,10 @@ def _softmax_chunk(carry, qg, q_pos, ci, kci, vci, *, chunk: int, k_offset,
     """One chunk's streaming-softmax update, the body that
     :func:`chunked_attention` scans and :func:`paged_attention` loops.
 
-    carry: (running max, denominator, accumulator) of [B,Hkv,g,Sq(,hd)];
-    qg: scaled f32 queries [B,Sq,Hkv,g,hd]; ci: chunk index; kci, vci:
-    chunk ``ci`` of the keys and values [B,chunk,Hkv,hd], keys at local
+    carry: (running max, denominator, accumulator) of [B,Hkv,g,Sq(,dv)];
+    qg: scaled f32 queries [B,Sq,Hkv,g,dk]; ci: chunk index; kci, vci:
+    chunk ``ci`` of the keys [B,chunk,Hkv,dk] and values [B,chunk,Hkv,dv]
+    (value width dv may differ from key width dk), keys at local
     positions ci*chunk + j (global k_offset + local).  A chunk every row
     masks wholly leaves a finite running max as it is (alpha = 1) and adds
     exact zeros."""
@@ -129,7 +167,8 @@ def _softmax_chunk(carry, qg, q_pos, ci, kci, vci, *, chunk: int, k_offset,
 
 
 def _softmax_init(b: int, hkv: int, group: int, sq: int, hd: int):
-    """The empty (running max, denominator, accumulator)."""
+    """The empty (running max, denominator, accumulator); ``hd`` is the
+    value width."""
     m0 = jnp.full((b, hkv, group, sq), -jnp.inf, jnp.float32)
     l0 = jnp.zeros((b, hkv, group, sq), jnp.float32)
     a0 = jnp.zeros((b, hkv, group, sq, hd), jnp.float32)
@@ -137,7 +176,7 @@ def _softmax_init(b: int, hkv: int, group: int, sq: int, hd: int):
 
 
 def _softmax_out(acc: jax.Array, l_run: jax.Array, dtype) -> jax.Array:
-    """Normalize the accumulator [B,Hkv,g,Sq,hd] into [B,Sq,Hq,hd]."""
+    """Normalize the accumulator [B,Hkv,g,Sq,dv] into [B,Sq,Hq,dv]."""
     b, hkv, group, sq, hd = acc.shape
     denom = jnp.maximum(l_run, 1e-30)
     out = acc / denom[..., None]                          # [B,Hkv,g,Sq,hd]
@@ -150,18 +189,21 @@ def chunked_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
                       q_offset=0, k_offset=0,
                       kv_valid: Optional[jax.Array] = None,
                       chunk: int = ATTN_CHUNK,
-                      with_stats: bool = False):
+                      with_stats: bool = False,
+                      scale: Optional[float] = None):
     """Streaming-softmax attention.
 
-    q: [B, Sq, Hq, hd]; k, v: [B, Skv, Hkv, hd] with Hq % Hkv == 0.
+    q: [B, Sq, Hq, hd]; k: [B, Skv, Hkv, hd], v: [B, Skv, Hkv, dv] with
+    Hq % Hkv == 0.  ``scale`` multiplies the scores (default hd^-0.5).
     Positions are q_offset+i / k_offset+j (offsets may be traced scalars —
     used by the sequence-sharded decode path).  When ``with_stats`` the
     returned value is (out, running_max, denom) for cross-shard LSE merges.
     """
     b, sq, hq, hd = q.shape
-    skv, hkv = k.shape[1], k.shape[2]
+    skv, hkv, dv = k.shape[1], k.shape[2], v.shape[-1]
     group = hq // hkv
-    scale = 1.0 / math.sqrt(hd)
+    if scale is None:
+        scale = 1.0 / math.sqrt(hd)
     qf = q.astype(jnp.float32) * scale
     q_off = jnp.asarray(q_offset)
     q_pos = (q_off[..., None] + jnp.arange(sq)) if q_off.ndim \
@@ -178,7 +220,7 @@ def chunked_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
         # positions that a kv_valid bound alone would wrongly admit.
         local_len = skv
     kc = k.reshape(b, n_chunks, chunk, hkv, hd)
-    vc = v.reshape(b, n_chunks, chunk, hkv, hd)
+    vc = v.reshape(b, n_chunks, chunk, hkv, dv)
 
     qg = qf.reshape(b, sq, hkv, group, hd)               # [B,Sq,Hkv,g,hd]
 
@@ -189,7 +231,7 @@ def chunked_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
                               window=window, kv_valid=kv_valid,
                               local_len=local_len), None
 
-    init = _softmax_init(b, hkv, group, sq, hd)
+    init = _softmax_init(b, hkv, group, sq, dv)
     idx = jnp.arange(n_chunks)
     (m_f, l_f, acc_f), _ = lax.scan(
         step, init,
@@ -255,6 +297,8 @@ def head_layout(cfg: ArchConfig, ctx: ParallelCtx):
 
 def init_attention(key, cfg: ArchConfig, dtype):
     """GLOBAL param shapes (shard_map in_specs produce the local views)."""
+    if cfg.mla is not None:
+        return init_mla(key, cfg, dtype)
     d, hd = cfg.d_model, cfg.head_dim_
     k1, k2, k3, k4 = jax.random.split(key, 4)
     std = 0.02
@@ -274,6 +318,8 @@ def init_attention(key, cfg: ArchConfig, dtype):
 def attention_specs(cfg: ArchConfig, model_axis: str):
     """PartitionSpecs matching init_attention (Q/O sharded, K/V replicated)."""
     from jax.sharding import PartitionSpec as P
+    if cfg.mla is not None:
+        return mla_specs(model_axis)
     p = {
         "wq": P(None, model_axis),
         "wk": P(None, None),
@@ -322,6 +368,12 @@ def attention_block(p, x: jax.Array, cfg: ArchConfig, ctx: ParallelCtx, *,
       --swa-override decode variant for full-attention archs).
     Returns (out [B,S,D], new_cache).
     """
+    if cfg.mla is not None:
+        if kv_cache is not None or xattn_kv is not None:
+            raise NotImplementedError(
+                "latent attention (MLA) decodes through PagedServeEngine "
+                "and its latent paged pool, not a dense K/V cache")
+        return mla_block(p, x, cfg, ctx, positions=positions), None
     b, s, d = x.shape
     hd = cfg.head_dim_
     hq_l, kv_w, group_l = head_layout(cfg, ctx)
@@ -468,16 +520,23 @@ def _seq_sharded_decode(q, k_new, v_new, kv_cache, cache_pos, cfg, ctx,
 # paged attention (continuous-batching serving, DESIGN.md §13)
 # ---------------------------------------------------------------------------
 
-def paged_attention(q: jax.Array, k_pool: jax.Array, v_pool: jax.Array,
+def paged_attention(q: jax.Array, k_pool: jax.Array,
+                    v_pool: Optional[jax.Array],
                     block_tables: jax.Array, *, positions: jax.Array,
                     kv_valid: jax.Array,
-                    window: Optional[int] = None) -> jax.Array:
+                    window: Optional[int] = None,
+                    scale: Optional[float] = None,
+                    v_width: Optional[int] = None) -> jax.Array:
     """Streaming-softmax attention of packed single-token rows over a
     paged pool, bit-identical to gathering each row's whole view (index i
     of it is position i) and calling :func:`chunked_attention` on it.
 
-    q: [T, 1, Hq, hd]; k_pool, v_pool: [n_blocks, block, Hkv, hd];
-    block_tables: [T, max_blocks]; positions / kv_valid: [T].
+    q: [T, 1, Hq, hd]; k_pool: [n_blocks, block, Hkv, hd]; v_pool:
+    [n_blocks, block, Hkv, dv], or None for a latent pool whose values
+    are the first ``v_width`` columns of its keys (latent attention's
+    absorbed form: one "head" of keys ``[c_kv | k_rope]``, values
+    ``c_kv``); block_tables: [T, max_blocks]; positions / kv_valid: [T].
+    ``scale`` multiplies the scores (default hd^-0.5).
 
     Iteration c gathers chunk c of every row straight from the pool and
     applies chunked_attention's update to it, so no whole view is ever
@@ -493,17 +552,20 @@ def paged_attention(q: jax.Array, k_pool: jax.Array, v_pool: jax.Array,
     math."""
     t, sq, hq, hd = q.shape
     nb, bs, hkv, _ = k_pool.shape
+    dv = v_width if v_pool is None else v_pool.shape[-1]
     group = hq // hkv
     chunk = ATTN_CHUNK                  # chunked_attention's, bit for bit
     maxb = block_tables.shape[1]
     span = maxb * bs
     n_chunks = -(-span // chunk)
     local_len = span if n_chunks * chunk != span else None
+    if scale is None:
+        scale = 1.0 / math.sqrt(hd)
     with jax.named_scope("attend"):
-        qf = q.astype(jnp.float32) * (1.0 / math.sqrt(hd))
+        qf = q.astype(jnp.float32) * scale
         q_pos = positions[:, None] + jnp.arange(sq)
         qg = qf.reshape(t, sq, hkv, group, hd)           # [T,Sq,Hkv,g,hd]
-        init = _softmax_init(t, hkv, group, sq, hd)
+        init = _softmax_init(t, hkv, group, sq, dv)
     with jax.named_scope("kv_gather"):
         if chunk % bs == 0:
             per = chunk // bs                   # whole blocks per chunk
@@ -512,12 +574,13 @@ def paged_attention(q: jax.Array, k_pool: jax.Array, v_pool: jax.Array,
 
             def gather(pool, c):
                 ids = lax.dynamic_slice_in_dim(tables, c * per, per, axis=1)
-                return pool[ids].reshape(t, chunk, hkv, hd)
+                return pool[ids].reshape(t, chunk, hkv, pool.shape[-1])
         else:
             n_tab = -(-n_chunks * chunk // bs)
             tables = jnp.pad(block_tables, ((0, 0), (0, n_tab - maxb)))
             k_pool = k_pool.reshape(nb * bs, hkv, hd)
-            v_pool = v_pool.reshape(nb * bs, hkv, hd)
+            if v_pool is not None:
+                v_pool = v_pool.reshape(nb * bs, hkv, dv)
 
             def gather(pool, c):
                 k_local = c * chunk + jnp.arange(chunk)
@@ -529,7 +592,7 @@ def paged_attention(q: jax.Array, k_pool: jax.Array, v_pool: jax.Array,
     def body(c, carry):
         with jax.named_scope("kv_gather"):
             kci = gather(k_pool, c)                      # [T,chunk,Hkv,hd]
-            vci = gather(v_pool, c)
+            vci = kci[..., :dv] if v_pool is None else gather(v_pool, c)
         with jax.named_scope("attend"):
             return _softmax_chunk(carry, qg, q_pos, c, kci, vci, chunk=chunk,
                                   k_offset=0, causal=True, window=window,
@@ -632,6 +695,173 @@ def paged_attention_block(p, x: jax.Array, cfg: ArchConfig,
         o = jnp.einsum("bsf,fd->bsd", out.reshape(b, s, hq_l * hd), p["wo"])
         o = ctx.tp_all_reduce(o)   # row-parallel combine — FlexLink path
     return o, new_pools
+
+
+# ---------------------------------------------------------------------------
+# latent attention (MLA; DeepSeek-V2 arXiv:2405.04434 §2.1)
+#
+# Heads shard over the model axis as GQA heads do: the up-projections
+# W_UQ and W_UKV and the output W_O split by head, the low-rank
+# down-projections (W_DQ, W_DKV) and their norms whole on every shard.
+# Each head's columns of W_UQ are [nope | rope], of W_UKV [k_nope | v].
+# Rotary positions rotate the two halves of the rope part (a permutation
+# of the published interleaved pairs, i.e. of random weight columns).
+# ---------------------------------------------------------------------------
+
+def mla_softmax_scale(cfg: ArchConfig) -> float:
+    """``qk_head_dim^-0.5``, times ``mscale(factor, mscale_all_dim)^2``
+    under YaRN."""
+    scale = cfg.mla.qk_head_dim ** -0.5
+    y = cfg.rope_scaling
+    if y is not None and y.mscale_all_dim:
+        scale *= yarn_mscale(y.factor, y.mscale_all_dim) ** 2
+    return scale
+
+
+def init_mla(key, cfg: ArchConfig, dtype):
+    """GLOBAL shapes of one MLA sublayer (mla_specs shards them)."""
+    m, d, h = cfg.mla, cfg.d_model, cfg.n_heads
+    ks = jax.random.split(key, 5)
+    std = 0.02
+    return {
+        "wq_a": jax.random.normal(ks[0], (d, m.q_lora_rank), dtype) * std,
+        "q_norm": jnp.ones((m.q_lora_rank,), dtype),
+        "wq_b": jax.random.normal(ks[1], (m.q_lora_rank,
+                                          h * m.qk_head_dim), dtype) * std,
+        "wkv_a": jax.random.normal(ks[2], (d, m.latent_width), dtype) * std,
+        "kv_norm": jnp.ones((m.kv_lora_rank,), dtype),
+        "wkv_b": jax.random.normal(
+            ks[3], (m.kv_lora_rank, h * (m.qk_nope_head_dim + m.v_head_dim)),
+            dtype) * std,
+        "wo": jax.random.normal(ks[4], (h * m.v_head_dim, d), dtype) * std,
+    }
+
+
+def mla_specs(model_axis: str):
+    from jax.sharding import PartitionSpec as P
+    return {"wq_a": P(None, None), "q_norm": P(None),
+            "wq_b": P(None, model_axis), "wkv_a": P(None, None),
+            "kv_norm": P(None), "wkv_b": P(None, model_axis),
+            "wo": P(model_axis, None)}
+
+
+def _mla_q(p, x, cfg: ArchConfig, positions):
+    """(q_nope, roped q_rope) [B,S,H_local,·]: ``c_q = RMSNorm(x W_DQ)``,
+    ``q = c_q W_UQ`` split per head."""
+    m = cfg.mla
+    b, s, _ = x.shape
+    cq = rms_norm(jnp.einsum("bsd,dr->bsr", x, p["wq_a"]), p["q_norm"],
+                  cfg.norm_eps)
+    q = jnp.einsum("bsr,rf->bsf", cq, p["wq_b"]).reshape(
+        b, s, -1, m.qk_head_dim)
+    q_rope = apply_rope(q[..., m.qk_nope_head_dim:], positions,
+                        cfg.rope_theta, cfg.rope_scaling)
+    return q[..., :m.qk_nope_head_dim], q_rope
+
+
+def _mla_latent(p, x, cfg: ArchConfig, positions):
+    """(normalised c_kv [B,S,r], roped shared key k_r [B,S,rope]):
+    ``[c_kv | k_r] = x W_DKV``."""
+    r = cfg.mla.kv_lora_rank
+    kv = jnp.einsum("bsd,dw->bsw", x, p["wkv_a"])
+    c = rms_norm(kv[..., :r], p["kv_norm"], cfg.norm_eps)
+    k_r = apply_rope(kv[..., None, r:], positions, cfg.rope_theta,
+                     cfg.rope_scaling)[:, :, 0]
+    return c, k_r
+
+
+def _mla_up(p, cfg: ArchConfig, heads: int):
+    """W_UKV per head: (W_UK [r, H, nope], W_UV [r, H, v])."""
+    m = cfg.mla
+    w = p["wkv_b"].reshape(m.kv_lora_rank, heads,
+                           m.qk_nope_head_dim + m.v_head_dim)
+    return w[..., :m.qk_nope_head_dim], w[..., m.qk_nope_head_dim:]
+
+
+def mla_block(p, x: jax.Array, cfg: ArchConfig, ctx: ParallelCtx, *,
+              positions=None) -> jax.Array:
+    """Causal MLA over [B, S, D] (train / prefill), keys and values
+    decompressed per head: ``k = [c_kv W_UK | k_r]``, ``v = c_kv W_UV``.
+    Named scopes ``mla_q``, ``mla_kv``, ``attn``, ``mla_out``."""
+    m = cfg.mla
+    b, s, _ = x.shape
+    if positions is None:
+        positions = jnp.arange(s)
+    with jax.named_scope("mla_q"):
+        q_nope, q_rope = _mla_q(p, x, cfg, positions)
+        heads = q_nope.shape[2]
+        q = jnp.concatenate([q_nope, q_rope.astype(q_nope.dtype)], -1)
+    with jax.named_scope("mla_kv"):
+        c, k_r = _mla_latent(p, x, cfg, positions)
+        w_uk, w_uv = _mla_up(p, cfg, heads)
+        k_nope = jnp.einsum("bsr,rhn->bshn", c, w_uk)
+        v = jnp.einsum("bsr,rhv->bshv", c, w_uv)
+        k = jnp.concatenate([k_nope, jnp.broadcast_to(
+            k_r[:, :, None].astype(k_nope.dtype),
+            (b, s, heads, m.qk_rope_head_dim))], -1)
+    with jax.named_scope("attn"):
+        out = chunked_attention(q, k, v, causal=True,
+                                scale=mla_softmax_scale(cfg))
+    with jax.named_scope("mla_out"):
+        o = jnp.einsum("bsf,fd->bsd", out.reshape(b, s, -1), p["wo"])
+        return ctx.tp_all_reduce(o)
+
+
+def mla_paged_block(p, x: jax.Array, cfg: ArchConfig, ctx: ParallelCtx, *,
+                    positions: jax.Array, kv_valid: jax.Array,
+                    pool: jax.Array, layer, block_tables: jax.Array):
+    """One MLA sublayer of packed single-token rows over the latent paged
+    pool of every layer ``[L, n_blocks, block, pool_width]``, of which it
+    writes and reads layer ``layer``'s blocks in place, in the absorbed
+    form: the pool holds each position's normalised ``c_kv`` and roped
+    ``k_r`` (then zeros to ``pool_width``); a row's query is ``[q_nope
+    W_UK^T | q_rope | 0]`` per head against it, one shared key "head"
+    whose values are ``c_kv``; the r-wide context goes through ``W_UV``,
+    then ``W_O``.  The same scores as :func:`mla_block`'s:
+    ``q_nope . (c W_UK) = (q_nope W_UK^T) . c``.
+
+    The write and the gather are :func:`paged_attention_block`'s: the
+    new latents are scattered before attention (padding rows write
+    nowhere), and :func:`paged_attention` gathers each 512-position chunk
+    from the pool up to the tick's deepest row.  Named scopes ``mla_q``,
+    ``mla_kv``, ``attn`` (``kv_write``, ``kv_gather``, ``attend``),
+    ``mla_out``.  Returns (out [T, 1, D], new pool)."""
+    m = cfg.mla
+    b, s, _ = x.shape
+    assert s == 1, "paged attention packs single-token rows"
+    pos2 = positions[:, None]
+    n_layers, nb, bs, width = pool.shape
+    pad = ((0, 0),) * 3 + ((0, width - m.latent_width),)
+    with jax.named_scope("mla_q"):
+        q_nope, q_rope = _mla_q(p, x, cfg, pos2)
+        w_uk, w_uv = _mla_up(p, cfg, q_nope.shape[2])
+        q_lat = jnp.einsum("bshn,rhn->bshr", q_nope, w_uk,
+                           preferred_element_type=jnp.float32)
+        q = jnp.pad(jnp.concatenate([q_lat, q_rope.astype(jnp.float32)],
+                                    -1), pad)
+    with jax.named_scope("mla_kv"):
+        c, k_r = _mla_latent(p, x, cfg, pos2)
+        latent = jnp.pad(jnp.concatenate([c, k_r.astype(c.dtype)], -1),
+                         pad[1:])[:, 0]
+    flat = pool.reshape(n_layers * nb * bs, width)
+    tables = block_tables + layer * nb             # this layer's blocks
+    with jax.named_scope("attn"):
+        with jax.named_scope("kv_write"):
+            blk = positions // bs
+            off = positions % bs
+            phys = jnp.take_along_axis(tables, blk[:, None], axis=1)[:, 0]
+            dest = jnp.where(kv_valid > 0, phys * bs + off,
+                             n_layers * nb * bs)
+            new_pool = flat.at[dest].set(latent.astype(pool.dtype),
+                                         mode="drop").reshape(pool.shape)
+        out = paged_attention(
+            q, new_pool.reshape(n_layers * nb, bs, 1, width), None, tables,
+            positions=positions, kv_valid=kv_valid,
+            scale=mla_softmax_scale(cfg), v_width=m.kv_lora_rank)
+    with jax.named_scope("mla_out"):
+        ctx_v = jnp.einsum("bshr,rhv->bshv", out.astype(x.dtype), w_uv)
+        o = jnp.einsum("bsf,fd->bsd", ctx_v.reshape(b, s, -1), p["wo"])
+        return ctx.tp_all_reduce(o), new_pool
 
 
 # ---------------------------------------------------------------------------

@@ -449,6 +449,11 @@ def init_cache(cfg: ArchConfig, ctx: ParallelCtx, dcfg: DecodeConfig,
                batch_local: int, dtype=None):
     """Zero cache pytree (local shapes — build under shard_map or use
     cache_specs for the global view)."""
+    if cfg.mla is not None:
+        raise ValueError(
+            f"{cfg.name}: latent attention (MLA) serves through "
+            f"PagedServeEngine, whose pool holds the latent; there is no "
+            f"dense K/V cache for it")
     dtype = dtype or cfg.dtype
     hd = cfg.head_dim_
     fam = cfg.family
@@ -518,13 +523,22 @@ PAGED_FAMILIES = ("dense", "vlm", "moe")
 def init_paged_pool(cfg: ArchConfig, ctx: ParallelCtx, pcfg: PagedConfig,
                     dtype=None):
     """Zero paged KV pool: ``[L, n_blocks, block_size, kv_w, hd]`` per K
-    and V.  Block contents are never zeroed again — reuse relies on
-    kv_valid masking (serving/paged_kv.py)."""
+    and V, or under latent attention one latent pool ``[L, n_blocks,
+    block_size, kv_lora_rank + qk_rope_head_dim]`` (``"latent"``).
+    ``pool_width`` is the latent width rounded up to the TPU's 128
+    lanes, where a 576-wide row lies in 640 in any layout that keeps
+    positions as rows; the pad columns stay zero.  Block contents are
+    never zeroed again — reuse relies on kv_valid masking
+    (serving/paged_kv.py)."""
     if cfg.family not in PAGED_FAMILIES:
         raise ValueError(
             f"paged serving supports {PAGED_FAMILIES}, got {cfg.family} "
             f"(ssm/hybrid/encdec stay on the wave engine)")
     dtype = dtype or cfg.dtype
+    if cfg.mla is not None:
+        return {"latent": jnp.zeros((cfg.n_layers, pcfg.n_blocks,
+                                     pcfg.block_size, cfg.mla.pool_width),
+                                    dtype)}
     kv_w = L.head_layout(cfg, ctx)[1]
     shape = (cfg.n_layers, pcfg.n_blocks, pcfg.block_size, kv_w,
              cfg.head_dim_)
@@ -544,16 +558,23 @@ def paged_decode_step(p, pool, tokens: jax.Array, positions: jax.Array,
         row's sequence-frontier row (engine ignores logits of rows that
         sampled nothing this tick)
 
-    Returns (logits [R, V_local], new pool).  Padding rows cost zero
-    attention mass and zero pool writes (layers.paged_attention_block).
+    Returns (logits [R, V_local], new pool); the MoE family returns a
+    third value, int32 ``[n_held + 1]``: the real rows' token-expert pairs
+    on each held expert, then all their pairs, summed over the expert
+    layers (moe.route_counts).  Padding rows cost zero attention mass and
+    zero pool writes (layers.paged_attention_block, mla_paged_block).
     Named scopes tag the compiled ops for the device trace: ``embed``;
     ``layers`` around the layer loop, inside it the attention sublayer's
-    own (``qkv_proj``, ``attn/...``, ``o_proj``) and ``mlp`` (or
-    ``moe``); ``head``.
+    own (``qkv_proj``, ``attn/...``, ``o_proj``; under latent attention
+    ``mla_q``, ``mla_kv``, ``attn/...``, ``mla_out``) and ``mlp`` (or
+    ``moe`` with ``route``, ``experts``, ``shared``); ``head``.
     """
     fam = cfg.family
     if fam not in PAGED_FAMILIES:
         raise ValueError(fam)
+    if cfg.mla is not None and pcfg.attn_impl != "reference":
+        raise ValueError("latent attention runs the reference paged path; "
+                         "the flash-decode kernel reads K/V pools")
     valid = row_req >= 0
     n_req = block_tables.shape[0]
     with jax.named_scope("attn"):
@@ -562,12 +583,12 @@ def paged_decode_step(p, pool, tokens: jax.Array, positions: jax.Array,
     with jax.named_scope("embed"):
         x = embed_tokens(p, tokens[:, None], cfg, ctx)       # [T, 1, D]
 
-    def attn(lp, x, kp, vp):
+    def attn(lp, x, pools):
         with jax.named_scope("qkv_proj"):
             h = L.rms_norm(x, lp["ln1"], cfg.norm_eps)
         h, new_pools = L.paged_attention_block(
             lp["attn"], h, cfg, ctx,
-            positions=positions, kv_valid=kv_valid, pools=(kp, vp),
+            positions=positions, kv_valid=kv_valid, pools=pools,
             block_tables=btab, window_override=pcfg.window_override,
             impl=pcfg.attn_impl)
         return x + h, new_pools
@@ -578,19 +599,22 @@ def paged_decode_step(p, pool, tokens: jax.Array, positions: jax.Array,
                 lp["mlp"], L.rms_norm(x, lp["ln2"], cfg.norm_eps), ctx)
 
     def step(x, inp):
-        lp, kp, vp = inp
-        x, (nkp, nvp) = attn(lp, x, kp, vp)
+        lp, pools = inp[0], inp[1:]
+        x, new_pools = attn(lp, x, pools)
         if "mlp" in lp:
-            x = mlp(lp, x)
-        else:
-            with jax.named_scope("moe"):
-                y, _ = M.moe_block(
-                    lp["moe"], L.rms_norm(x, lp["ln2"], cfg.norm_eps),
-                    cfg, ctx)
-            x = x + y
-        return x, (nkp, nvp)
+            return mlp(lp, x), new_pools
+        with jax.named_scope("moe"):
+            y, _, counts = M.moe_layer(
+                lp["moe"], L.rms_norm(x, lp["ln2"], cfg.norm_eps),
+                cfg, ctx, valid=valid)
+        return x + y, new_pools + (counts,)
 
-    pool_k, pool_v = pool["k"], pool["v"]
+    if cfg.mla is not None:
+        return _paged_mla_layers(p, pool["latent"], x, positions, kv_valid,
+                                 btab, valid, sample_rows, cfg, ctx, mlp)
+
+    names = ("k", "v")
+    pools = tuple(pool[n] for n in names)
     # "layers": the layer loop's own ops (each layer's weights and pool
     # sliced from the stacks, the updated pool written back)
     with jax.named_scope("layers"):
@@ -598,23 +622,75 @@ def paged_decode_step(p, pool, tokens: jax.Array, positions: jax.Array,
             npre = cfg.moe.n_dense_prefix
             for i in range(npre):
                 lp = jax.tree.map(lambda a: a[i], p["prefix"])
-                x, (nkp, nvp) = attn(lp, x, pool_k[i], pool_v[i])
-                pool_k = pool_k.at[i].set(nkp)
-                pool_v = pool_v.at[i].set(nvp)
+                x, new_pools = attn(lp, x, tuple(a[i] for a in pools))
+                pools = tuple(a.at[i].set(n)
+                              for a, n in zip(pools, new_pools))
                 x = mlp(lp, x)
-            x, (nkp, nvp) = lax.scan(step, x, (p["layers"], pool_k[npre:],
-                                               pool_v[npre:]))
-            pool_k = pool_k.at[npre:].set(nkp)
-            pool_v = pool_v.at[npre:].set(nvp)
+            x, ys = lax.scan(step, x, (p["layers"],)
+                             + tuple(a[npre:] for a in pools))
+            pools = tuple(a.at[npre:].set(n) for a, n in zip(pools, ys))
         else:
-            x, (pool_k, pool_v) = lax.scan(step, x, (p["layers"], pool_k,
-                                                     pool_v))
+            x, ys = lax.scan(step, x, (p["layers"],) + pools)
+            pools = ys[:len(names)]
 
     with jax.named_scope("head"):
-        x = L.rms_norm(x, p["final_norm"], cfg.norm_eps)
-        xs = x[jnp.clip(sample_rows, 0, x.shape[0] - 1)]     # [R, 1, D]
-        logits_l = lm_logits_local(p, xs, cfg, ctx)[:, 0]    # [R, V_l]
-    return logits_l, {"k": pool_k, "v": pool_v}
+        logits_l = _paged_head(p, x, sample_rows, cfg, ctx)
+    new_pool = dict(zip(names, pools))
+    if fam == "moe":
+        return logits_l, new_pool, ys[-1].sum(axis=0)
+    return logits_l, new_pool
+
+
+def _paged_head(p, x, sample_rows, cfg, ctx):
+    x = L.rms_norm(x, p["final_norm"], cfg.norm_eps)
+    xs = x[jnp.clip(sample_rows, 0, x.shape[0] - 1)]         # [R, 1, D]
+    return lm_logits_local(p, xs, cfg, ctx)[:, 0]            # [R, V_l]
+
+
+def _paged_mla_layers(p, pool, x, positions, kv_valid, btab, valid,
+                      sample_rows, cfg, ctx, mlp):
+    """The layer loop of :func:`paged_decode_step` over a latent pool
+    ``[L, n_blocks, block, pool_width]``: the loop carries the whole pool
+    and each layer writes and reads its own blocks in place
+    (layers.mla_paged_block's ``layer``), so no layer's pool is sliced
+    out or written back."""
+    def attn(lp, x, pool, layer):
+        with jax.named_scope("mla_q"):
+            h = L.rms_norm(x, lp["ln1"], cfg.norm_eps)
+        h, pool = L.mla_paged_block(
+            lp["attn"], h, cfg, ctx, positions=positions, kv_valid=kv_valid,
+            pool=pool, layer=layer, block_tables=btab)
+        return x + h, pool
+
+    counts = jnp.zeros((cfg.moe.n_held + 1,), jnp.int32) \
+        if cfg.family == "moe" else None
+
+    def step(carry, inp):
+        x, pool, counts = carry
+        lp, layer = inp
+        x, pool = attn(lp, x, pool, layer)
+        if "mlp" in lp:
+            return (mlp(lp, x), pool, counts), None
+        with jax.named_scope("moe"):
+            y, _, c = M.moe_layer(
+                lp["moe"], L.rms_norm(x, lp["ln2"], cfg.norm_eps), cfg, ctx,
+                valid=valid)
+        return (x + y, pool, counts + c), None
+
+    npre = cfg.moe.n_dense_prefix if "prefix" in p else 0
+    with jax.named_scope("layers"):
+        for i in range(npre):
+            lp = jax.tree.map(lambda a: a[i], p["prefix"])
+            x, pool = attn(lp, x, pool, i)
+            x = mlp(lp, x)
+        (x, pool, counts), _ = lax.scan(
+            step, (x, pool, counts),
+            (p["layers"], jnp.arange(npre, cfg.n_layers)))
+    with jax.named_scope("head"):
+        logits_l = _paged_head(p, x, sample_rows, cfg, ctx)
+    if counts is None:
+        return logits_l, {"latent": pool}
+    return logits_l, {"latent": pool}, counts
 
 
 def decode_step(p, cache, token: jax.Array, pos: jax.Array,

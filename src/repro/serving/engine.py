@@ -73,6 +73,8 @@ class ServeEngine:
         self.scfg = scfg
         self.dcfg = DecodeConfig(cache_len_local=scfg.cache_len,
                                  seq_shard=None)
+        # latent attention has no dense cache: init_cache names the
+        # paged engine
         self.cache = init_cache(cfg, ctx, self.dcfg, scfg.slots)
         self.pos = np.zeros(scfg.slots, np.int32)
         self.active: List[Optional[Request]] = [None] * scfg.slots
@@ -284,11 +286,14 @@ def paged_step_builder(cfg: ArchConfig, ctx: ParallelCtx,
                        pcfg: PagedConfig):
     """A FRESH jit wrapper of the packed step (jax.jit memoizes per
     function identity).  The named function gives the compiled module a
-    stable name (``jit_paged_step``) in the device trace."""
+    stable name (``jit_paged_step``) in the device trace.  The latent
+    pool (MLA) is donated, so the step updates it in place instead of
+    copying the whole pool into a new buffer every step."""
     def paged_step(p, pool, toks, pos, rows, tables, sample):
         return paged_decode_step(p, pool, toks, pos, rows, tables, sample,
                                  cfg, ctx, pcfg)
-    return jax.jit(paged_step)
+    return jax.jit(paged_step,
+                   donate_argnums=(1,) if cfg.mla is not None else ())
 
 
 def paged_step_texts(cfg: ArchConfig, ctx: ParallelCtx,
@@ -365,6 +370,10 @@ class PagedServeEngine:
         # K/V chunks the reference attention ran, and those of the span
         self._chunks_run = 0
         self._chunks_span = 0
+        # token-expert pairs of real rows on each held expert, then all
+        # (the MoE step's third output, summed over steps)
+        self._moe_counts = (np.zeros(cfg.moe.n_held + 1, np.int64)
+                            if cfg.family == "moe" else None)
 
     def _step_builder(self):
         """A fresh jit wrapper per build; the shape_key bucket keeps each
@@ -442,9 +451,13 @@ class PagedServeEngine:
         with TraceAnnotation("serve.issue"):
             self._program.issue(self.p, self.pool, *args, shape_key=t_b)
         with TraceAnnotation("serve.await"):
-            logits, self.pool = self._program.await_all()[-1]
+            logits, self.pool, *counts = self._program.await_all()[-1]
         with TraceAnnotation("serve.fetch"):
-            logits = np.asarray(logits)
+            if counts:
+                logits, (counts,) = jax.device_get((logits, counts))
+                self._moe_counts += counts
+            else:
+                logits = np.asarray(logits)
         with TraceAnnotation("serve.sample"):
             sampled = {}
             for row in plan.sample_rows:
@@ -479,10 +492,14 @@ class PagedServeEngine:
         """The engine's counts since it was built.  ``attn_chunks``: the
         512-position K/V chunks the reference attention ran (``run``, up
         to each step's deepest row) against those of the whole block-table
-        span (``span``), summed over steps; zero under the kernel."""
+        span (``span``), summed over steps; zero under the kernel.
+        ``moe`` (MoE family): token-expert pairs routed by real rows
+        (``assigned``), those that fell on the experts this device holds
+        (``held``), and ``per_expert`` for each held expert, summed over
+        the expert layers and steps."""
         ec = self._program.cache.report()
         lookups = ec["hits"] + ec["rebuilds"]
-        return {
+        rep = {
             "engine": "paged",
             "ticks": self._ticks,
             "steps": self._steps,
@@ -503,6 +520,11 @@ class PagedServeEngine:
             "attn_chunks": {"run": self._chunks_run,
                             "span": self._chunks_span},
         }
+        if self._moe_counts is not None:
+            per = [int(n) for n in self._moe_counts[:-1]]
+            rep["moe"] = {"assigned": int(self._moe_counts[-1]),
+                          "held": sum(per), "per_expert": per}
+        return rep
 
     def comm_report(self) -> Dict[str, object]:
         rep = dict(self.ctx.comm_report())

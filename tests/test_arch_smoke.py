@@ -43,7 +43,7 @@ def test_full_config_matches_assignment(arch):
     expect = {
         "mixtral_8x7b": (32, 4096, 32, 8, 14336, 32000),
         "internvl2_76b": (80, 8192, 64, 8, 28672, 128256),
-        "kimi_k2_1t_a32b": (61, 7168, 64, 8, 2048, 163840),
+        "kimi_k2_1t_a32b": (61, 7168, 64, 64, 18432, 163840),
         "deepseek_67b": (95, 8192, 64, 8, 22016, 102400),
         "starcoder2_15b": (40, 6144, 48, 4, 24576, 49152),
         "whisper_medium": (24, 1024, 16, 16, 4096, 51865),
@@ -62,8 +62,20 @@ def test_arch_extras():
     assert get_config("mixtral_8x7b").moe.n_experts == 8
     assert get_config("mixtral_8x7b").moe.top_k == 2
     assert get_config("mixtral_8x7b").sliding_window == 4096
-    k2 = get_config("kimi_k2_1t_a32b").moe
+    kimi = get_config("kimi_k2_1t_a32b")
+    k2 = kimi.moe
     assert (k2.n_experts, k2.top_k) == (384, 8)
+    assert (k2.n_dense_prefix, k2.d_expert, k2.n_shared) == (1, 2048, 1)
+    assert (k2.scoring, k2.routed_scale, k2.held) == ("sigmoid", 2.827, None)
+    m = kimi.mla
+    assert (m.q_lora_rank, m.kv_lora_rank, m.qk_nope_head_dim,
+            m.qk_rope_head_dim, m.v_head_dim) == (1536, 512, 128, 64, 128)
+    y = kimi.rope_scaling
+    assert (kimi.rope_theta, y.factor, y.original_max_position, y.beta_fast,
+            y.beta_slow, y.mscale, y.mscale_all_dim) == \
+        (50000.0, 32.0, 4096, 1.0, 1.0, 1.0, 1.0)
+    assert kimi.norm_eps == 1e-6 and not kimi.tie_embeddings
+    assert "arXiv:2412.19437" in kimi.source
     assert get_config("mamba2_1p3b").ssm.d_state == 128
     assert get_config("zamba2_1p2b").ssm.d_state == 64
     assert get_config("qwen2_72b").qkv_bias

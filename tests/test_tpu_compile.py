@@ -124,6 +124,52 @@ def test_paged_serve_step_fits_one_v5e_chip(one_chip):
     assert 9e9 < used < 16e9
 
 
+def test_kimi_latent_step_updates_its_pool_in_place_on_one_v5e_chip(
+        one_chip):
+    """The benchmark's Kimi K2 step: published widths, 1 dense + 8 expert
+    layers, 8 of 384 experts held, vocabulary 20480, at its top bucket of
+    256 rows over the latent pool of 16 requests of 12288 positions
+    (2.27 GB).  It fits one chip's 16 GB, and the donated pool is written
+    in place: aliased to the output, never copied whole."""
+    import dataclasses
+    import functools
+    import re
+
+    from repro.configs import get_config
+    from repro.models import init_params, single_device_ctx
+    from repro.models.transformer import init_paged_pool
+    from repro.serving.engine import PagedServeConfig, paged_step_builder
+
+    base = get_config("kimi-k2-1t-a32b")
+    cfg = dataclasses.replace(base, n_layers=9, vocab=20480,
+                              moe=dataclasses.replace(base.moe, held=(0, 8)))
+    ctx = single_device_ctx()
+    scfg = PagedServeConfig(max_requests=16, cache_len=12288, kv_block=16,
+                            max_tokens_in_flight=256, min_bucket=8)
+    pcfg = scfg.paged()
+    on_chip = lambda t: jax.tree.map(  # noqa: E731
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip),
+        t)
+    i32 = lambda *s: jax.ShapeDtypeStruct(s, jnp.int32,  # noqa: E731
+                                          sharding=one_chip)
+    params = jax.eval_shape(lambda k: init_params(k, cfg),
+                            jax.random.PRNGKey(0))
+    pool = jax.eval_shape(functools.partial(init_paged_pool, cfg, ctx, pcfg))
+    rows, reqs = scfg.max_tokens_in_flight, scfg.max_requests
+    compiled = paged_step_builder(cfg, ctx, pcfg).lower(
+        on_chip(params), on_chip(pool), i32(rows), i32(rows), i32(rows),
+        i32(reqs, pcfg.max_blocks_per_req), i32(reqs)).compile()
+    mem = compiled.memory_analysis()
+    latent = pool["latent"]
+    assert latent.shape == (9, 12288, 16, 640)
+    assert mem.alias_size_in_bytes >= latent.size * 2
+    used = (mem.argument_size_in_bytes + mem.output_size_in_bytes
+            + mem.temp_size_in_bytes - mem.alias_size_in_bytes)
+    assert 11e9 < used < 16e9
+    whole = re.escape("bf16[9,12288,16,640]") + r"\{[^}]*\} copy\("
+    assert not re.search(whole, compiled.as_text())
+
+
 def test_flex_all_reduce_compiles_staged_kernel_for_v5e(topo, monkeypatch):
     # a trace interpreted earlier in this process must not be reused
     jax.clear_caches()
