@@ -603,9 +603,30 @@ def paged_attention(q: jax.Array, k_pool: jax.Array,
         return _softmax_out(acc_f, l_f, q.dtype)
 
 
+def _paged_write(pools, new, block_tables: jax.Array, layer,
+                 positions: jax.Array, kv_valid: jax.Array):
+    """Scatter the packed rows ``new`` (one [T, ...] array per pool) into
+    the stacked pools ``[L, n_blocks, block, ...]``, in layer ``layer``'s
+    blocks, at each row's position.  Padding rows (``kv_valid`` 0) write
+    nowhere: an out-of-bounds row, dropped.  Returns (new pools, the row
+    tables offset to the layer's blocks, as paged_attention reads them
+    from the pools reshaped to ``[L * n_blocks, block, ...]``)."""
+    n_layers, nb, bs = pools[0].shape[:3]
+    flat = [a.reshape(n_layers * nb * bs, *a.shape[3:]) for a in pools]
+    tables = block_tables + layer * nb             # this layer's blocks
+    with jax.named_scope("attn"), jax.named_scope("kv_write"):
+        blk = positions // bs
+        off = positions % bs
+        phys = jnp.take_along_axis(tables, blk[:, None], axis=1)[:, 0]
+        dest = jnp.where(kv_valid > 0, phys * bs + off, n_layers * nb * bs)
+        return tuple(f.at[dest].set(n.astype(a.dtype),
+                                    mode="drop").reshape(a.shape)
+                     for a, f, n in zip(pools, flat, new)), tables
+
+
 def paged_attention_block(p, x: jax.Array, cfg: ArchConfig,
                           ctx: ParallelCtx, *, positions: jax.Array,
-                          kv_valid: jax.Array, pools, block_tables,
+                          kv_valid: jax.Array, pools, layer, block_tables,
                           window_override="cfg",
                           impl: str = "reference"):
     """One attention sublayer over a PAGED KV pool (packed serving layout).
@@ -616,8 +637,11 @@ def paged_attention_block(p, x: jax.Array, cfg: ArchConfig,
     kv_valid     : [T] int32 — row t attends cache positions < kv_valid[t];
                    0 marks a bucket-padding row (zero attention mass, no
                    cache write)
-    pools        : (k_pool, v_pool) [n_blocks, block_size, kv_w, hd] — ONE
-                   layer's physical block pool
+    pools        : (k_pool, v_pool) [L, n_blocks, block_size, kv_w, hd] —
+                   EVERY layer's physical block pool, stacked
+    layer        : this sublayer's index into the stack; it writes and
+                   reads its own blocks (block ``j`` of layer ``l`` is row
+                   ``l * n_blocks + j`` of the flattened stack) in place
     block_tables : [T, max_blocks] int32 — per-ROW tables (the engine
                    gathers its per-request tables out to packed rows)
     impl         : "reference" (:func:`paged_attention`: chunked_attention's
@@ -635,7 +659,8 @@ def paged_attention_block(p, x: jax.Array, cfg: ArchConfig,
     ``qkv_proj``; ``attn`` with ``kv_write``, ``kv_gather`` and
     ``attend``; ``o_proj``.
 
-    Returns (out [T, 1, D], (new_k_pool, new_v_pool)).
+    Returns (out [T, 1, D], (new_k_pool, new_v_pool)), the stacked pools
+    with this layer's new rows written.
     """
     b, s, d = x.shape
     assert s == 1, "paged attention packs single-token rows"
@@ -662,34 +687,19 @@ def paged_attention_block(p, x: jax.Array, cfg: ArchConfig,
             q = apply_rope(q, pos2, cfg.rope_theta)
             k = apply_rope(k, pos2, cfg.rope_theta)
 
-    kp, vp = pools
-    nb, bs_blk = kp.shape[0], kp.shape[1]
-    kp_flat = kp.reshape(nb * bs_blk, kv_w, hd)
-    vp_flat = vp.reshape(nb * bs_blk, kv_w, hd)
+    new_pools, tables = _paged_write(pools, (k[:, 0], v[:, 0]),
+                                     block_tables, layer, positions, kv_valid)
+    n_layers, nb = pools[0].shape[:2]
+    blocks = [a.reshape(n_layers * nb, *a.shape[2:]) for a in new_pools]
     with jax.named_scope("attn"):
-        with jax.named_scope("kv_write"):
-            blk = positions // bs_blk
-            off = positions % bs_blk
-            phys = jnp.take_along_axis(block_tables, blk[:, None],
-                                       axis=1)[:, 0]
-            # padding rows write nowhere: OOB destination + mode="drop"
-            dest = jnp.where(kv_valid > 0, phys * bs_blk + off, nb * bs_blk)
-            kp_flat = kp_flat.at[dest].set(k[:, 0].astype(kp.dtype),
-                                           mode="drop")
-            vp_flat = vp_flat.at[dest].set(v[:, 0].astype(vp.dtype),
-                                           mode="drop")
-            new_pools = (kp_flat.reshape(kp.shape), vp_flat.reshape(vp.shape))
-
         if impl == "kernel":
             from repro.kernels import ops as K
             with jax.named_scope("attend"):
-                out = K.paged_flash_decode(q[:, 0], new_pools[0],
-                                           new_pools[1], block_tables,
+                out = K.paged_flash_decode(q[:, 0], *blocks, tables,
                                            kv_valid, window=window)[:, None]
         else:
-            out = paged_attention(q, *new_pools, block_tables,
-                                  positions=positions, kv_valid=kv_valid,
-                                  window=window)
+            out = paged_attention(q, *blocks, tables, positions=positions,
+                                  kv_valid=kv_valid, window=window)
 
     with jax.named_scope("o_proj"):
         o = jnp.einsum("bsf,fd->bsd", out.reshape(b, s, hq_l * hd), p["wo"])
@@ -843,17 +853,9 @@ def mla_paged_block(p, x: jax.Array, cfg: ArchConfig, ctx: ParallelCtx, *,
         c, k_r = _mla_latent(p, x, cfg, pos2)
         latent = jnp.pad(jnp.concatenate([c, k_r.astype(c.dtype)], -1),
                          pad[1:])[:, 0]
-    flat = pool.reshape(n_layers * nb * bs, width)
-    tables = block_tables + layer * nb             # this layer's blocks
+    (new_pool,), tables = _paged_write((pool,), (latent,), block_tables,
+                                       layer, positions, kv_valid)
     with jax.named_scope("attn"):
-        with jax.named_scope("kv_write"):
-            blk = positions // bs
-            off = positions % bs
-            phys = jnp.take_along_axis(tables, blk[:, None], axis=1)[:, 0]
-            dest = jnp.where(kv_valid > 0, phys * bs + off,
-                             n_layers * nb * bs)
-            new_pool = flat.at[dest].set(latent.astype(pool.dtype),
-                                         mode="drop").reshape(pool.shape)
         out = paged_attention(
             q, new_pool.reshape(n_layers * nb, bs, 1, width), None, tables,
             positions=positions, kv_valid=kv_valid,

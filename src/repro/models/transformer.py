@@ -551,6 +551,9 @@ def paged_decode_step(p, pool, tokens: jax.Array, positions: jax.Array,
                       ctx: ParallelCtx, pcfg: PagedConfig):
     """One packed continuous-batching step (context + generation phases).
 
+    pool                     : init_paged_pool's dict, every layer stacked:
+        ``{"k", "v"}`` or ``{"latent"}``; its keys pick the attention
+        sublayer (layers.paged_attention_block or mla_paged_block)
     tokens/positions/row_req : [T] int32 — packed rows; ``row_req`` maps a
         row to its request row (block-table row), -1 for bucket padding
     block_tables             : [R, max_blocks_per_req] int32
@@ -562,7 +565,10 @@ def paged_decode_step(p, pool, tokens: jax.Array, positions: jax.Array,
     third value, int32 ``[n_held + 1]``: the real rows' token-expert pairs
     on each held expert, then all their pairs, summed over the expert
     layers (moe.route_counts).  Padding rows cost zero attention mass and
-    zero pool writes (layers.paged_attention_block, mla_paged_block).
+    zero pool writes.  One layer loop serves both kinds of pool: it
+    carries the whole stacked pool, and each layer writes and reads its
+    own blocks in place, so a caller that donates ``pool``
+    (serving/engine.paged_step_builder) gets it updated in place.
     Named scopes tag the compiled ops for the device trace: ``embed``;
     ``layers`` around the layer loop, inside it the attention sublayer's
     own (``qkv_proj``, ``attn/...``, ``o_proj``; under latent attention
@@ -583,87 +589,31 @@ def paged_decode_step(p, pool, tokens: jax.Array, positions: jax.Array,
     with jax.named_scope("embed"):
         x = embed_tokens(p, tokens[:, None], cfg, ctx)       # [T, 1, D]
 
-    def attn(lp, x, pools):
+    def attn(lp, x, pool, layer):
+        # the attention sublayer of the pool's kind: K/V or latent
+        if "latent" in pool:
+            with jax.named_scope("mla_q"):
+                h = L.rms_norm(x, lp["ln1"], cfg.norm_eps)
+            h, latent = L.mla_paged_block(
+                lp["attn"], h, cfg, ctx, positions=positions,
+                kv_valid=kv_valid, pool=pool["latent"], layer=layer,
+                block_tables=btab)
+            return x + h, {"latent": latent}
         with jax.named_scope("qkv_proj"):
             h = L.rms_norm(x, lp["ln1"], cfg.norm_eps)
-        h, new_pools = L.paged_attention_block(
-            lp["attn"], h, cfg, ctx,
-            positions=positions, kv_valid=kv_valid, pools=pools,
-            block_tables=btab, window_override=pcfg.window_override,
-            impl=pcfg.attn_impl)
-        return x + h, new_pools
+        h, (k, v) = L.paged_attention_block(
+            lp["attn"], h, cfg, ctx, positions=positions, kv_valid=kv_valid,
+            pools=(pool["k"], pool["v"]), layer=layer, block_tables=btab,
+            window_override=pcfg.window_override, impl=pcfg.attn_impl)
+        return x + h, {"k": k, "v": v}
 
     def mlp(lp, x):
         with jax.named_scope("mlp"):
             return x + L.mlp_block(
                 lp["mlp"], L.rms_norm(x, lp["ln2"], cfg.norm_eps), ctx)
 
-    def step(x, inp):
-        lp, pools = inp[0], inp[1:]
-        x, new_pools = attn(lp, x, pools)
-        if "mlp" in lp:
-            return mlp(lp, x), new_pools
-        with jax.named_scope("moe"):
-            y, _, counts = M.moe_layer(
-                lp["moe"], L.rms_norm(x, lp["ln2"], cfg.norm_eps),
-                cfg, ctx, valid=valid)
-        return x + y, new_pools + (counts,)
-
-    if cfg.mla is not None:
-        return _paged_mla_layers(p, pool["latent"], x, positions, kv_valid,
-                                 btab, valid, sample_rows, cfg, ctx, mlp)
-
-    names = ("k", "v")
-    pools = tuple(pool[n] for n in names)
-    # "layers": the layer loop's own ops (each layer's weights and pool
-    # sliced from the stacks, the updated pool written back)
-    with jax.named_scope("layers"):
-        if fam == "moe" and "prefix" in p:
-            npre = cfg.moe.n_dense_prefix
-            for i in range(npre):
-                lp = jax.tree.map(lambda a: a[i], p["prefix"])
-                x, new_pools = attn(lp, x, tuple(a[i] for a in pools))
-                pools = tuple(a.at[i].set(n)
-                              for a, n in zip(pools, new_pools))
-                x = mlp(lp, x)
-            x, ys = lax.scan(step, x, (p["layers"],)
-                             + tuple(a[npre:] for a in pools))
-            pools = tuple(a.at[npre:].set(n) for a, n in zip(pools, ys))
-        else:
-            x, ys = lax.scan(step, x, (p["layers"],) + pools)
-            pools = ys[:len(names)]
-
-    with jax.named_scope("head"):
-        logits_l = _paged_head(p, x, sample_rows, cfg, ctx)
-    new_pool = dict(zip(names, pools))
-    if fam == "moe":
-        return logits_l, new_pool, ys[-1].sum(axis=0)
-    return logits_l, new_pool
-
-
-def _paged_head(p, x, sample_rows, cfg, ctx):
-    x = L.rms_norm(x, p["final_norm"], cfg.norm_eps)
-    xs = x[jnp.clip(sample_rows, 0, x.shape[0] - 1)]         # [R, 1, D]
-    return lm_logits_local(p, xs, cfg, ctx)[:, 0]            # [R, V_l]
-
-
-def _paged_mla_layers(p, pool, x, positions, kv_valid, btab, valid,
-                      sample_rows, cfg, ctx, mlp):
-    """The layer loop of :func:`paged_decode_step` over a latent pool
-    ``[L, n_blocks, block, pool_width]``: the loop carries the whole pool
-    and each layer writes and reads its own blocks in place
-    (layers.mla_paged_block's ``layer``), so no layer's pool is sliced
-    out or written back."""
-    def attn(lp, x, pool, layer):
-        with jax.named_scope("mla_q"):
-            h = L.rms_norm(x, lp["ln1"], cfg.norm_eps)
-        h, pool = L.mla_paged_block(
-            lp["attn"], h, cfg, ctx, positions=positions, kv_valid=kv_valid,
-            pool=pool, layer=layer, block_tables=btab)
-        return x + h, pool
-
     counts = jnp.zeros((cfg.moe.n_held + 1,), jnp.int32) \
-        if cfg.family == "moe" else None
+        if fam == "moe" else None
 
     def step(carry, inp):
         x, pool, counts = carry
@@ -677,6 +627,9 @@ def _paged_mla_layers(p, pool, x, positions, kv_valid, btab, valid,
                 valid=valid)
         return (x + y, pool, counts + c), None
 
+    # "layers": the layer loop's own ops.  The loop carries the whole
+    # stacked pool and each layer writes and reads its own blocks in
+    # place, so no layer's pool is sliced out or written back.
     npre = cfg.moe.n_dense_prefix if "prefix" in p else 0
     with jax.named_scope("layers"):
         for i in range(npre):
@@ -689,8 +642,14 @@ def _paged_mla_layers(p, pool, x, positions, kv_valid, btab, valid,
     with jax.named_scope("head"):
         logits_l = _paged_head(p, x, sample_rows, cfg, ctx)
     if counts is None:
-        return logits_l, {"latent": pool}
-    return logits_l, {"latent": pool}, counts
+        return logits_l, pool
+    return logits_l, pool, counts
+
+
+def _paged_head(p, x, sample_rows, cfg, ctx):
+    x = L.rms_norm(x, p["final_norm"], cfg.norm_eps)
+    xs = x[jnp.clip(sample_rows, 0, x.shape[0] - 1)]         # [R, 1, D]
+    return lm_logits_local(p, xs, cfg, ctx)[:, 0]            # [R, V_l]
 
 
 def decode_step(p, cache, token: jax.Array, pos: jax.Array,

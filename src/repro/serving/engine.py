@@ -286,14 +286,14 @@ def paged_step_builder(cfg: ArchConfig, ctx: ParallelCtx,
                        pcfg: PagedConfig):
     """A FRESH jit wrapper of the packed step (jax.jit memoizes per
     function identity).  The named function gives the compiled module a
-    stable name (``jit_paged_step``) in the device trace.  The latent
-    pool (MLA) is donated, so the step updates it in place instead of
-    copying the whole pool into a new buffer every step."""
+    stable name (``jit_paged_step``) in the device trace.  The pool, K/V
+    or latent, is donated: the step writes its rows into it in place
+    instead of copying the whole pool into a new buffer every step, so
+    a caller must use only the pool the step returns."""
     def paged_step(p, pool, toks, pos, rows, tables, sample):
         return paged_decode_step(p, pool, toks, pos, rows, tables, sample,
                                  cfg, ctx, pcfg)
-    return jax.jit(paged_step,
-                   donate_argnums=(1,) if cfg.mla is not None else ())
+    return jax.jit(paged_step, donate_argnums=(1,))
 
 
 def paged_step_texts(cfg: ArchConfig, ctx: ParallelCtx,

@@ -552,3 +552,139 @@ def test_serving_report_counts_attention_chunks(setup):
     assert rep["attn_chunks"] == {"run": sum(-(-d // 512) for d in deepest),
                                   "span": 5 * 2}
     assert rep["attn_chunks"]["run"] == 8
+
+
+# ---------------------------------------------------------------------------
+# the layer loop carries the stacked pool: against the per-layer form
+# ---------------------------------------------------------------------------
+
+def _per_layer_step(p, pool, tokens, positions, row_req, block_tables,
+                    sample_rows, cfg, ctx, pcfg):
+    """The former K/V layer loop of ``paged_decode_step``: a scan over
+    each layer's pool sliced out of the stack, the updated pools written
+    back (the dense prefix of a MoE stack one layer at a time)."""
+    from repro.models import layers as L
+    from repro.models import moe as M
+    from repro.models.transformer import _paged_head, embed_tokens
+    from jax import lax
+
+    valid = row_req >= 0
+    btab = block_tables[jnp.clip(row_req, 0, block_tables.shape[0] - 1)]
+    kv_valid = jnp.where(valid, positions + 1, 0)
+    x = embed_tokens(p, tokens[:, None], cfg, ctx)
+
+    def attn(lp, x, pools):                  # one layer's (k, v) pools
+        h = L.rms_norm(x, lp["ln1"], cfg.norm_eps)
+        h, new = L.paged_attention_block(
+            lp["attn"], h, cfg, ctx, positions=positions, kv_valid=kv_valid,
+            pools=tuple(a[None] for a in pools), layer=0, block_tables=btab,
+            window_override=pcfg.window_override, impl=pcfg.attn_impl)
+        return x + h, tuple(a[0] for a in new)
+
+    def mlp(lp, x):
+        return x + L.mlp_block(lp["mlp"], L.rms_norm(x, lp["ln2"],
+                                                     cfg.norm_eps), ctx)
+
+    def step(x, inp):
+        lp, pools = inp[0], inp[1:]
+        x, new = attn(lp, x, pools)
+        if "mlp" in lp:
+            return mlp(lp, x), new
+        y, _, counts = M.moe_layer(
+            lp["moe"], L.rms_norm(x, lp["ln2"], cfg.norm_eps), cfg, ctx,
+            valid=valid)
+        return x + y, new + (counts,)
+
+    pools = (pool["k"], pool["v"])
+    npre = cfg.moe.n_dense_prefix if "prefix" in p else 0
+    for i in range(npre):
+        lp = jax.tree.map(lambda a: a[i], p["prefix"])
+        x, new = attn(lp, x, tuple(a[i] for a in pools))
+        pools = tuple(a.at[i].set(n) for a, n in zip(pools, new))
+        x = mlp(lp, x)
+    x, ys = lax.scan(step, x, (p["layers"],)
+                     + tuple(a[npre:] for a in pools))
+    pools = tuple(a.at[npre:].set(n) for a, n in zip(pools, ys))
+    logits = _paged_head(p, x, sample_rows, cfg, ctx)
+    out = (logits, {"k": pools[0], "v": pools[1]})
+    return out + (ys[-1].sum(axis=0),) if cfg.family == "moe" else out
+
+
+def _three_ticks(block, maxb):
+    """Three packed ticks of three requests (16 rows each, padding
+    last): prefill chunks, then decode rows beside later prefill chunks.
+    Each request holds ``maxb`` pool blocks of its own, scattered."""
+    reqs = 3
+    perm = np.random.default_rng(11).permutation(reqs * maxb * 2)[
+        :reqs * maxb]
+    tables = perm.reshape(reqs, maxb).astype(np.int32)
+    plans = [  # (request row, first position, rows) per request
+        [(0, 0, 7), (1, 0, 5)],
+        [(0, 7, 1), (1, 5, 6), (2, 0, 8)],
+        [(0, 8, 1), (1, 11, 1), (2, 8, 9)],
+    ]
+    ticks = []
+    for plan in plans:
+        tok = np.zeros(16, np.int32)
+        pos = np.zeros(16, np.int32)
+        row = np.full(16, -1, np.int32)
+        sample = np.zeros(reqs, np.int32)
+        i = 0
+        for r, first, n in plan:
+            tok[i:i + n] = np.arange(n) * 7 + 13 * r + first + 1
+            pos[i:i + n] = np.arange(first, first + n)
+            row[i:i + n] = r
+            sample[r] = i + n - 1
+            i += n
+        ticks.append(tuple(jnp.asarray(a)
+                           for a in (tok, pos, row, tables, sample)))
+    return ticks
+
+
+@pytest.mark.parametrize("impl", ["reference", "kernel"])
+@pytest.mark.parametrize("arch", ["glm4-9b", "mixtral-8x7b+prefix"])
+def test_paged_step_carries_the_stacked_pool_bit_identical(arch, impl):
+    """The step whose layer loop carries the whole stacked K/V pool (each
+    layer writing and reading its own blocks in place, the pool donated)
+    gives the logits, the written pools and the MoE route counts of the
+    per-layer form (each layer's pool sliced out and written back) bit
+    for bit, over three ticks that mix prefill and decode rows on a pool
+    of random stale contents."""
+    import dataclasses
+
+    from repro.models.transformer import PagedConfig
+    from repro.serving.engine import paged_step_builder
+
+    cfg = get_config(arch.split("+")[0]).reduced(n_layers=3)
+    if arch.endswith("+prefix"):
+        cfg = dataclasses.replace(
+            cfg, moe=dataclasses.replace(cfg.moe, n_dense_prefix=1))
+    ctx = single_device_ctx()
+    params = init_params(KEY, cfg)
+    block, maxb = 4, 5
+    pcfg = PagedConfig(block_size=block, n_blocks=3 * maxb * 2,
+                       max_blocks_per_req=maxb, attn_impl=impl)
+    shape = (cfg.n_layers, pcfg.n_blocks, block, cfg.n_kv_heads,
+             cfg.head_dim_)
+    kk, kv = jax.random.split(jax.random.PRNGKey(5))
+    stale = {"k": jax.random.normal(kk, shape, cfg.dtype),
+             "v": jax.random.normal(kv, shape, cfg.dtype)}
+    pool = jax.tree.map(jnp.copy, stale)
+    want_pool = jax.tree.map(jnp.copy, stale)       # the oracle's own
+    step = paged_step_builder(cfg, ctx, pcfg)
+    oracle = jax.jit(lambda p, pl, *a: _per_layer_step(p, pl, *a, cfg, ctx,
+                                                       pcfg))
+    for args in _three_ticks(block, maxb):
+        got = step(params, pool, *args)
+        want = oracle(params, want_pool, *args)
+        assert len(got) == len(want) == (3 if cfg.family == "moe" else 2)
+        pool, want_pool = got[1], want[1]
+        assert jnp.array_equal(got[0], want[0])
+        for name in ("k", "v"):
+            assert jnp.array_equal(pool[name], want_pool[name]), name
+        if cfg.family == "moe":
+            assert jnp.array_equal(got[2], want[2])
+            expert_layers = cfg.n_layers - cfg.moe.n_dense_prefix
+            assert int(got[2][-1]) == (expert_layers * cfg.moe.top_k
+                                       * int(jnp.sum(args[2] >= 0)))
+    assert not jnp.array_equal(pool["k"], stale["k"])

@@ -170,6 +170,54 @@ def test_kimi_latent_step_updates_its_pool_in_place_on_one_v5e_chip(
     assert not re.search(whole, compiled.as_text())
 
 
+def test_dense_paged_step_updates_its_kv_pool_in_place_on_v5e(one_chip):
+    """The dense step of the deepseek-67b chat cell at published widths
+    and with its pool (32 requests of 2560 positions: 5120 blocks, 168 MB
+    a layer, more than the chip's fast memory holds), cut to 2 layers and
+    16 rows: the loop carries the stacked K and V pools and the step
+    donates them, so both are aliased to the output, no op's result is
+    one layer's pool sliced out of the stack, and no dynamic-update-slice
+    or copy writes the whole stack."""
+    import dataclasses
+    import functools
+    import re
+
+    from repro.configs import get_config
+    from repro.models import init_params, single_device_ctx
+    from repro.models.transformer import init_paged_pool
+    from repro.serving.engine import PagedServeConfig, paged_step_builder
+
+    cfg = dataclasses.replace(get_config("deepseek-67b"), n_layers=2)
+    ctx = single_device_ctx()
+    scfg = PagedServeConfig(max_requests=32, cache_len=2560, kv_block=16,
+                            max_tokens_in_flight=16, min_bucket=16)
+    pcfg = scfg.paged()
+    on_chip = lambda t: jax.tree.map(  # noqa: E731
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip),
+        t)
+    i32 = lambda *s: jax.ShapeDtypeStruct(s, jnp.int32,  # noqa: E731
+                                          sharding=one_chip)
+    params = jax.eval_shape(lambda k: init_params(k, cfg),
+                            jax.random.PRNGKey(0))
+    pool = jax.eval_shape(functools.partial(init_paged_pool, cfg, ctx, pcfg))
+    rows, reqs = scfg.max_tokens_in_flight, scfg.max_requests
+    compiled = paged_step_builder(cfg, ctx, pcfg).lower(
+        on_chip(params), on_chip(pool), i32(rows), i32(rows), i32(rows),
+        i32(reqs, pcfg.max_blocks_per_req), i32(reqs)).compile()
+    k = pool["k"]
+    assert k.shape == (2, 5120, 16, 8, 128) and k.dtype == jnp.bfloat16
+    assert compiled.memory_analysis().alias_size_in_bytes >= 2 * k.size * 2
+    text = compiled.as_text()
+    assert "bf16[1,5120,16,8,128]" not in text         # one layer's pool
+    whole = re.compile(r"^\s*(?:ROOT )?%?(\S+) = bf16\[2,5120,16,8,128\]"
+                       r"\S* ([\w-]+)\(", re.M)
+    writes = whole.findall(text)
+    assert writes                      # the parameters and the scatter
+    for name, op in writes:
+        assert op not in ("copy", "dynamic-update-slice"), (name, op)
+        assert "dynamic-update-slice" not in name, (name, op)
+
+
 def test_flex_all_reduce_compiles_staged_kernel_for_v5e(topo, monkeypatch):
     # a trace interpreted earlier in this process must not be reused
     jax.clear_caches()
